@@ -13,9 +13,6 @@ Conventions:
     scale broadcasts back against ``q`` without reshapes and rides any
     gather/scatter the quantized tensor itself rides;
   * scales are ALWAYS float32 regardless of the storage dtype.
-
-fp8 availability is probed with ``hasattr`` (older jaxlibs lack the
-dtype); callers gate on :func:`fp8_dtype` instead of importing it.
 """
 from __future__ import annotations
 
@@ -33,28 +30,20 @@ _FP8_E4M3_MAX = 448.0
 _EPS = 1e-12
 
 
-def fp8_dtype():
-    """``jnp.float8_e4m3fn`` when this jaxlib has it, else None."""
-    return getattr(jnp, "float8_e4m3fn", None)
+_QMAX = {jnp.dtype(jnp.int8): _INT8_MAX,
+         jnp.dtype(jnp.float8_e4m3fn): _FP8_E4M3_MAX}
 
 
 def is_quantized(dtype) -> bool:
     """True for storage dtypes that need a scale array (int8 / fp8)."""
-    dtype = jnp.dtype(dtype)
-    if dtype == jnp.int8:
-        return True
-    f8 = fp8_dtype()
-    return f8 is not None and dtype == jnp.dtype(f8)
+    return jnp.dtype(dtype) in _QMAX
 
 
 def qmax(dtype) -> float:
     dtype = jnp.dtype(dtype)
-    if dtype == jnp.int8:
-        return _INT8_MAX
-    f8 = fp8_dtype()
-    if f8 is not None and dtype == jnp.dtype(f8):
-        return _FP8_E4M3_MAX
-    raise ValueError(f"not a quantized storage dtype: {dtype}")
+    if dtype not in _QMAX:
+        raise ValueError(f"not a quantized storage dtype: {dtype}")
+    return _QMAX[dtype]
 
 
 def quantize(x: jax.Array, axis: Axis = None,
@@ -83,24 +72,16 @@ def dequantize(q: jax.Array, scale: jax.Array, dtype=jnp.float32):
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
-_KV_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
+_KV_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8,
+              "fp8": jnp.float8_e4m3fn}
 
 
 def resolve_kv_dtype(name: Optional[str]):
     """Map a ``--kv-dtype`` CLI name to a storage dtype (None -> None,
-    i.e. 'use the engine's cache_dtype').  Raises for 'fp8' when this
-    jaxlib has no float8 support — quantized serving must not silently
-    fall back to a wider dtype."""
+    i.e. 'use the engine's cache_dtype')."""
     if name is None:
         return None
     if name in _KV_DTYPES:
         return _KV_DTYPES[name]
-    if name == "fp8":
-        f8 = fp8_dtype()
-        if f8 is None:
-            raise ValueError(
-                "kv_dtype='fp8' requested but this jaxlib has no "
-                "float8_e4m3fn; use 'int8' (same byte width) instead")
-        return f8
     raise ValueError(f"unknown kv_dtype {name!r} "
                      f"(choose from f32, bf16, int8, fp8)")

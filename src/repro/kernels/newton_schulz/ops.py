@@ -1,7 +1,8 @@
 """Jit'd public wrapper for Newton–Schulz orthogonalization.
 
-Dispatch: TPU backend -> Pallas (fused kernel when the matrix + Gram fit in
-VMEM, tiled-matmul composition otherwise); other backends -> jnp reference.
+Dispatch: TPU backend -> Pallas (fused kernel when the matrix fits the VMEM
+the fused kernel may claim, tiled-matmul composition otherwise); other
+backends -> jnp reference.
 """
 from __future__ import annotations
 
@@ -13,14 +14,11 @@ import jax.numpy as jnp
 from repro.kernels.newton_schulz import kernel as K
 from repro.kernels.newton_schulz.ref import NS_COEFFS, newton_schulz_ref
 
-# Budget for the fused path: matrix + gram + temps in f32 must fit VMEM.
-_VMEM_BUDGET = 96 * 2**20
 
-
-def _fits_fused(n: int, m: int) -> bool:
-    mat = n * m * 4
-    gram = n * n * 4
-    return 3 * mat + 2 * gram < _VMEM_BUDGET
+def fits_fused(n: int, m: int) -> bool:
+    """True iff an (n, m) f32 matrix (padded) fits the fused kernel's VMEM
+    limit."""
+    return K.fused_vmem_bytes(n, m) <= K.FUSED_VMEM_LIMIT
 
 
 def _pad_to(x, mult: int = 128):
@@ -31,39 +29,42 @@ def _pad_to(x, mult: int = 128):
     return x, (n, m)
 
 
-def _ns_large(x: jax.Array, steps: int) -> jax.Array:
+def _ns_tiled(x: jax.Array, steps: int, interpret: bool) -> jax.Array:
     """NS via tiled Pallas matmuls for matrices too large to fuse."""
     a, b, c = NS_COEFFS
+    mm = functools.partial(K.matmul, interpret=interpret)
     x = x / (jnp.linalg.norm(x) + 1e-7)
     for _ in range(steps):
-        gram = K.matmul(x, x.T)
-        poly = b * gram + c * K.matmul(gram, gram)
-        x = a * x + K.matmul(poly, x)
+        gram = mm(x, x.T)
+        poly = b * gram + c * mm(gram, gram)
+        x = a * x + mm(poly, x)
     return x
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "force"))
-def newton_schulz(m: jax.Array, steps: int = 5, force: str = "auto") -> jax.Array:
-    """Orthogonalize one matrix (n_in, n_out).  `force` in
-    {'auto','pallas','ref'} (tests pin the path)."""
-    use_pallas = force == "pallas" or (
-        force == "auto" and jax.default_backend() == "tpu")
-    if not use_pallas:
-        return newton_schulz_ref(m, steps)
-
+def newton_schulz_pallas(m: jax.Array, steps: int = 5,
+                         interpret: bool = False) -> jax.Array:
+    """The Pallas path: orthogonalize (n_in, n_out) with the wide side last,
+    padded to multiples of 128."""
     x = m.astype(jnp.float32)
     transpose = x.shape[0] > x.shape[1]
     if transpose:
         x = x.T
     x, (n0, m0) = _pad_to(x)
-    interpret = jax.default_backend() != "tpu"
-    if _fits_fused(*x.shape):
+    if fits_fused(*x.shape):
         # Padding keeps the Frobenius norm and the Gram spectrum: NS of the
         # padded matrix restricted to the original block equals NS(x).
         y = K.ns_fused(x, steps=steps, interpret=interpret)
     else:
-        y = _ns_large(x, steps)
+        y = _ns_tiled(x, steps, interpret)
     y = y[:n0, :m0]
     if transpose:
         y = y.T
     return y.astype(m.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("steps",))
+def newton_schulz(m: jax.Array, steps: int = 5) -> jax.Array:
+    """Orthogonalize one matrix (n_in, n_out)."""
+    if jax.default_backend() == "tpu":
+        return newton_schulz_pallas(m, steps)
+    return newton_schulz_ref(m, steps)

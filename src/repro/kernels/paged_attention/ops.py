@@ -25,15 +25,10 @@ from repro.kernels.paged_attention import ref
 from repro.kernels.paged_attention.kernel import paged_attention_tpu
 
 
-def use_pallas(force: str = "auto") -> bool:
-    return force == "pallas" or (force == "auto"
-                                 and jax.default_backend() == "tpu")
-
-
 def paged_attention_decode(q, k_pages, v_pages, k_new, v_new, page, off,
                            block_table, index, *, k_scales=None,
                            v_scales=None, logit_softcap: float = 0.0,
-                           force: str = "auto", shard_fn=None):
+                           shard_fn=None):
     """Fused write + attend for one decode step over the paged pool.
 
     q: (B,1,H,hd); k_new/v_new: (B,KV,hd) — the new token's K/V; page/off:
@@ -59,7 +54,7 @@ def paged_attention_decode(q, k_pages, v_pages, k_new, v_new, page, off,
     else:
         k_w = k_new.astype(k_pages.dtype)
         v_w = v_new.astype(v_pages.dtype)
-    if use_pallas(force):
+    if jax.default_backend() == "tpu":
         k_pages = k_pages.at[page, off].set(k_w)
         v_pages = v_pages.at[page, off].set(v_w)
         new_cache = {"k_pages": k_pages, "v_pages": v_pages}
@@ -71,8 +66,7 @@ def paged_attention_decode(q, k_pages, v_pages, k_new, v_new, page, off,
         out = paged_attention_tpu(
             q, k_pages, v_pages, block_table, index,
             k_scales=k_scales, v_scales=v_scales,
-            logit_softcap=logit_softcap,
-            interpret=jax.default_backend() != "tpu")
+            logit_softcap=logit_softcap)
         return out, new_cache
     if quantized:
         # Deferred dense-select uses the round-tripped values: exactly what

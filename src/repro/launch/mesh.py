@@ -4,9 +4,9 @@ Functions (not module constants) so importing never touches jax device
 state.  Single pod: 16x16 = 256 chips (data, model).  Multi-pod: 2x16x16 =
 512 chips with a leading 'pod' pure-DP axis (gradient all-reduce over DCN).
 
-``make_mesh`` is a version-compat shim: newer jax wants explicit
-``axis_types`` while jax<=0.4 does not accept the argument at all.  All mesh
-construction in the repo goes through it.
+All mesh construction in the repo goes through ``make_mesh``, which makes
+every axis ``AxisType.Auto`` (GSPMD propagation, as the sharding rules in
+``distributed/sharding.py`` assume).
 """
 from __future__ import annotations
 
@@ -17,15 +17,10 @@ import jax
 
 def make_mesh(shape: Sequence[int], axis_names: Sequence[str],
               devices=None) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions (axis_types only where supported)."""
-    if hasattr(jax.sharding, "AxisType"):
-        try:
-            return jax.make_mesh(
-                tuple(shape), tuple(axis_names), devices=devices,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(shape), tuple(axis_names), devices=devices)
+    """jax.make_mesh with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names), devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -105,6 +105,7 @@ import numpy as np
 
 from repro import configs as cfglib
 from repro.checkpoint import checkpointer as ckpt
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.models import registry
 from repro.train import faults as faults_lib
@@ -133,6 +134,8 @@ def load_params(checkpoint_dir: str, cfg, step=None, dtype=None):
 
 
 def main(argv=None):
+    """Run the CLI; returns the per-request results with ``--continuous``,
+    else the ``GenerateResult``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2-12l")
     ap.add_argument("--smoke", action="store_true")
@@ -225,6 +228,7 @@ def main(argv=None):
                     help="audit pool refcounts/commitments + radix pins "
                          "every N scheduler iterations (0: off)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.paged and not args.continuous:
         raise SystemExit("--paged requires --continuous")
     spec = args.spec_depth is not None or args.draft_checkpoint is not None
@@ -330,7 +334,7 @@ def main(argv=None):
                   f"acceptance={ss['acceptance_rate']:.2%} "
                   f"mean_accepted_len="
                   f"{np.mean(mal) if mal else 0.0:.2f}")
-        return
+        return results
 
     prompts = rng.integers(0, cfg.vocab_size,
                            (args.batch, args.prompt_len)).astype(np.int32)
@@ -344,6 +348,7 @@ def main(argv=None):
           f"batch={args.batch} decode_steps={res.steps}")
     print(f"prefill tokens/s={pf:.1f}  decode tokens/s={dec:.1f}")
     print("sample:", res.tokens[0, :24].tolist())
+    return res
 
 
 if __name__ == "__main__":

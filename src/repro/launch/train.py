@@ -29,11 +29,13 @@ example: --faults ckpt.write:1,train.iter:120:crash --nan-policy skip
 from repro import configs as cfglib
 from repro.configs.base import (ExpansionConfig, OptimizerConfig,
                                 ScheduleConfig, TrainConfig)
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_lib
 from repro.train import loop
 
 
 def main(argv=None):
+    """Run the CLI; returns the ``TrainResult``."""
     ap = argparse.ArgumentParser(
         epilog=FAULT_GRAMMAR,
         formatter_class=argparse.RawDescriptionHelpFormatter)
@@ -93,6 +95,7 @@ def main(argv=None):
                     help="fail a train step as a train.step fault if it "
                     "exceeds this wall time instead of stalling")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     cfg = (cfglib.get_smoke_config(args.arch) if args.smoke
            else cfglib.get_config(args.arch))
@@ -137,6 +140,7 @@ def main(argv=None):
     if args.history_out:
         with open(args.history_out, "w") as f:
             json.dump(res.history, f)
+    return res
 
 
 if __name__ == "__main__":

@@ -55,9 +55,9 @@ def _apply_layout(spec: P) -> P:
     return P(*out)
 
 
-# Concrete mesh registered by the train/serve engine.  On jax versions with
-# an abstract-mesh context (>=0.5) that context wins; on older jax the
-# engine's registration is the only way activation constraints resolve, so
+# Concrete mesh registered by the train/serve engine.  An abstract-mesh
+# context (``jax.sharding.use_mesh``) wins where one is set; otherwise the
+# engine's registration is how activation constraints resolve, so
 # maybe_shard is a no-op unless an engine is active.
 _ACTIVE_MESH: Optional[jax.sharding.Mesh] = None
 
@@ -73,13 +73,8 @@ def get_active_mesh() -> Optional[jax.sharding.Mesh]:
 
 
 def _current_mesh():
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except AttributeError:
-        pass
-    return _ACTIVE_MESH
+    mesh = jax.sharding.get_abstract_mesh()
+    return _ACTIVE_MESH if mesh.empty else mesh
 
 
 def maybe_shard(x: jax.Array, spec: P) -> jax.Array:
@@ -92,8 +87,6 @@ def maybe_shard(x: jax.Array, spec: P) -> jax.Array:
         # Drop axes the current mesh doesn't have (e.g. 'pod' on single-pod)
         # and axes whose size doesn't divide the dimension (e.g. 8 KV heads
         # on a 16-way 'model' axis) — replicate those dims instead.
-        names = set(mesh.axis_names)
-        sizes = dict(mesh.shape)
         clean = []
         for i, entry in enumerate(spec):
             dim = x.shape[i] if i < x.ndim else 1
@@ -101,13 +94,7 @@ def maybe_shard(x: jax.Array, spec: P) -> jax.Array:
                 clean.append(None)
                 continue
             axes = entry if isinstance(entry, (tuple, list)) else (entry,)
-            kept = []
-            prod = 1
-            for a in axes:
-                if a in names and dim % (prod * sizes[a]) == 0:
-                    kept.append(a)
-                    prod *= sizes[a]
-            clean.append(tuple(kept) if kept else None)
+            clean.append(mesh_axes_dividing(mesh, dim, axes))
         clean = clean[:x.ndim]
         if isinstance(mesh, jax.sharding.Mesh):     # concrete (engine) mesh
             return jax.lax.with_sharding_constraint(
@@ -115,6 +102,30 @@ def maybe_shard(x: jax.Array, spec: P) -> jax.Array:
         return jax.lax.with_sharding_constraint(x, P(*clean))
     except Exception:
         return x
+
+
+def mesh_axes_dividing(mesh, dim: int, axes) -> Optional[tuple]:
+    """The prefix-greedy subset of ``axes`` present in ``mesh`` whose size
+    product divides ``dim`` (None when empty) — a PartitionSpec entry."""
+    sizes = dict(mesh.shape)
+    kept, prod = [], 1
+    for a in axes:
+        if a in sizes and dim % (prod * sizes[a]) == 0:
+            kept.append(a)
+            prod *= sizes[a]
+    return tuple(kept) or None
+
+
+def kernel_shard_map(fn, in_specs, out_specs):
+    """Wrap a Pallas kernel call for the engine's mesh.  The TPU compiler
+    cannot partition a Pallas kernel, so on a multi-device mesh the kernel
+    runs per shard under ``shard_map``; on one device (or outside an
+    engine) ``fn`` is returned unchanged."""
+    mesh = _ACTIVE_MESH
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 BATCH_SPEC = P(("pod", "data"))           # activations: batch over DP axes

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import OptimizerConfig
 from repro.optim.base import Optimizer, clip_by_global_norm
@@ -55,13 +56,21 @@ def orthogonalize(m: jax.Array, steps: int = 5) -> jax.Array:
     """Newton–Schulz quintic iteration (Muon).  Orthogonalizes the trailing
     two dims; leading dims (layer stack, experts) are vmapped.
 
-    Routes through the Pallas kernel on TPU (repro.kernels.newton_schulz).
+    Routes through the Pallas kernel on TPU (repro.kernels.newton_schulz);
+    on a multi-device mesh every device orthogonalizes the whole
+    (gathered) matrix, since the kernel cannot be partitioned.
     """
     from repro.kernels.newton_schulz import ops as ns_ops
-    lead = m.shape[:-2]
-    x = m.reshape((-1,) + m.shape[-2:])
-    y = jax.vmap(lambda a: ns_ops.newton_schulz(a, steps=steps))(x)
-    return y.reshape(lead + m.shape[-2:])
+    from repro.models import common
+
+    def run(m):
+        x = m.reshape((-1,) + m.shape[-2:])
+        y = jax.vmap(lambda a: ns_ops.newton_schulz(a, steps=steps))(x)
+        return y.reshape(m.shape)
+
+    if jax.default_backend() == "tpu":
+        run = common.kernel_shard_map(run, (P(),), P())
+    return run(m)
 
 
 def muon_nsgd(cfg: OptimizerConfig) -> Optimizer:

@@ -1,0 +1,163 @@
+"""Compile-only tests: the Pallas kernels of the GPT-2 train and serve path,
+at ``gpt2-12l`` widths, compiled by the TPU compiler for a described
+``v5e:2x2`` chip (nothing runs; no chip is needed).
+
+Interpret-mode tests (``test_kernels.py``) cannot see what the chip's
+compiler refuses: block shapes that break the (8, 128) tiling, kernels
+that need more VMEM than they may use, a kernel without a backward pass.
+These can.  The kernel functions are called with ``interpret=False``
+directly, because the ``ops.py`` dispatchers read ``jax.default_backend()``,
+which is the CPU here.
+
+The topology is described inside a module fixture (never at import), so
+every pytest-xdist worker collects the same tests and only the worker that
+runs this file loads the TPU compiler.  The persistent compilation cache is
+off around these compiles: an entry written for a described chip cannot be
+read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import configs as cfglib
+from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.newton_schulz import kernel as ns_kernel
+from repro.kernels.newton_schulz import ops as ns_ops
+from repro.kernels.paged_attention.kernel import paged_attention_tpu
+from repro.models import registry
+from repro.optim import muon
+
+GPT2 = cfglib.get_config("gpt2-12l")
+B, S = 8, 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Lower and compile ``fn`` for the described chip; returns the HLO text
+    of the compiled program."""
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _qkv(one_chip, dtype=jnp.float32):
+    H, hd = GPT2.num_heads, GPT2.head_dim
+    return [_struct((B, S, H, hd), dtype, one_chip) for _ in range(3)]
+
+
+# ---------------------------------------------------------------------------
+# flash attention
+# ---------------------------------------------------------------------------
+
+def test_flash_forward_compiles(one_chip):
+    hlo = _compile(lambda q, k, v: flash_attention_tpu(q, k, v), *_qkv(one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_forward_backward_compiles(one_chip):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_tpu(q, k, v) ** 2)
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
+    # forward (recomputed residuals) + dq + dk/dv kernels
+    assert hlo.count("tpu_custom_call") >= 3
+
+
+# ---------------------------------------------------------------------------
+# Newton–Schulz: every muon matrix of gpt2-12l
+# ---------------------------------------------------------------------------
+
+def _muon_shapes():
+    """Distinct (n_in, n_out) of the leaves Muon orthogonalizes."""
+    api = registry.get_model(GPT2)
+    p = jax.eval_shape(lambda k: api.init(k, GPT2), jax.random.PRNGKey(0))
+    out = set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(p)[0]:
+        if muon._is_matrix(path, leaf):
+            out.add(tuple(leaf.shape[-2:]))
+    return sorted(out)
+
+
+def test_muon_shapes_cover_the_model():
+    d, ff, V = GPT2.d_model, GPT2.d_ff, GPT2.vocab_size
+    assert set(_muon_shapes()) == {(d, d), (d, ff), (ff, d), (V, d)}
+
+
+@pytest.mark.parametrize("shape", [(768, 768), (768, 3072), (3072, 768),
+                                   (50304, 768)])
+def test_newton_schulz_compiles(one_chip, shape):
+    x = _struct(shape, jnp.float32, one_chip)
+    hlo = _compile(lambda m: ns_ops.newton_schulz_pallas(m, interpret=False),
+                   x)
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("shape", [(768, 768), (768, 3072)])
+def test_newton_schulz_compiles_over_a_layer_stack(one_chip, shape):
+    """Muon vmaps the kernel over the scanned layer stack."""
+    x = _struct((12,) + shape, jnp.float32, one_chip)
+    _compile(jax.vmap(lambda m: ns_ops.newton_schulz_pallas(
+        m, interpret=False)), x)
+
+
+def test_newton_schulz_fused_path_compiles_at_its_vmem_limit(one_chip):
+    """The largest matrix the fused path accepts compiles under the VMEM
+    limit the kernel states in its compiler params."""
+    n = 128
+    while ns_ops.fits_fused(n + 128, n + 128):
+        n += 128
+    x = _struct((n, n), jnp.float32, one_chip)
+    _compile(lambda m: ns_kernel.ns_fused(m, interpret=False), x)
+
+
+# ---------------------------------------------------------------------------
+# paged decode
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("pool_dtype", [jnp.float32, jnp.int8])
+@pytest.mark.parametrize("block_size", [16, 128])
+def test_paged_decode_compiles(one_chip, pool_dtype, block_size):
+    H, KV, hd = GPT2.num_heads, GPT2.num_kv_heads, GPT2.head_dim
+    rows, max_len = 4, 1024
+    nb = max_len // block_size
+    NP = rows * nb + 1
+    q = _struct((rows, 1, H, hd), jnp.float32, one_chip)
+    pages = _struct((NP, block_size, KV, hd), pool_dtype, one_chip)
+    table = _struct((rows, nb), jnp.int32, one_chip)
+    index = _struct((rows,), jnp.int32, one_chip)
+    args = [q, pages, pages, table, index]
+    if pool_dtype == jnp.int8:
+        scales = _struct((NP, block_size, KV, 1), jnp.float32, one_chip)
+
+        def fn(q, kp, vp, t, i, ks, vs):
+            return paged_attention_tpu(q, kp, vp, t, i, k_scales=ks,
+                                       v_scales=vs, interpret=False)
+        args += [scales, scales]
+    else:
+        def fn(q, kp, vp, t, i):
+            return paged_attention_tpu(q, kp, vp, t, i, interpret=False)
+    hlo = _compile(fn, *args)
+    assert "tpu_custom_call" in hlo

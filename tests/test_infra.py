@@ -253,3 +253,28 @@ def test_straggler_monitor():
     time.sleep(0.05)
     _, slow = m.stop()
     assert slow
+
+
+# ---------------------------------------------------------------------------
+# persistent compilation cache placement
+# ---------------------------------------------------------------------------
+
+def test_compile_cache_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to one fixed, gitignored directory of the checkout.
+    Importing the module turned nothing on."""
+    from pathlib import Path
+
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    root = Path(__file__).resolve().parents[1]
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root / ".jax_cache")
+        assert ".jax_cache/" in (root / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
 from repro.kernels.flash_attention.kernel import flash_attention_tpu
 from repro.kernels.mamba_scan.kernel import selective_scan_tpu
@@ -16,6 +17,8 @@ from repro.kernels.paged_attention import ref as pa_ref
 from repro.kernels.paged_attention.kernel import paged_attention_tpu
 from repro.kernels.rwkv6.kernel import wkv_tpu
 from repro.kernels.rwkv6.ref import wkv_ref
+from repro.launch import mesh as mesh_lib
+from repro.models import common
 
 
 # ---------------------------------------------------------------------------
@@ -54,6 +57,55 @@ def test_flash_attention_masks(window, softcap, causal):
                               **kw)
     np.testing.assert_allclose(np.asarray(blk), np.asarray(ref), atol=2e-5)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,KV,causal,window,softcap", [
+    (64, 64, 4, 2, True, 0, 0.0),        # GQA causal
+    (128, 128, 2, 2, True, 16, 20.0),     # window + softcap
+    (64, 64, 2, 1, False, 0, 0.0),        # MQA, bidirectional
+    (16, 40, 2, 2, False, 0, 0.0),        # cross-attention, Sq != Sk
+])
+def test_flash_attention_grad_vs_ref(Sq, Sk, H, KV, causal, window, softcap):
+    """The custom-VJP backward (Pallas dq and dk/dv kernels) against
+    autodiff through the naive oracle."""
+    B, hd = 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q = jax.random.normal(ks[0], (B, Sq, H, hd))
+    k = jax.random.normal(ks[1], (B, Sk, KV, hd))
+    v = jax.random.normal(ks[2], (B, Sk, KV, hd))
+    do = jax.random.normal(ks[3], (B, Sq, H, hd))
+    kw = dict(causal=causal, window=window, logit_softcap=softcap)
+
+    def pal(q, k, v):
+        return jnp.sum(flash_attention_tpu(q, k, v, block_q=32, block_k=32,
+                                           interpret=True, **kw) * do)
+
+    def ref(q, k, v):
+        return jnp.sum(fa_ref.naive_attention(q, k, v, **kw) * do)
+
+    for got, want in zip(jax.grad(pal, (0, 1, 2))(q, k, v),
+                         jax.grad(ref, (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_flash_attention_sharded_over_mesh():
+    """On a multi-device mesh the kernel runs per shard (batch over
+    'data', heads over 'model'); the result equals the unsharded oracle."""
+    mesh = mesh_lib.make_train_mesh("4x2")
+    B, S, H, hd = 4, 64, 4, 16
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q, k, v = (jax.random.normal(ks[i], (B, S, H, hd)) for i in range(3))
+    prev = common.get_active_mesh()
+    common.set_active_mesh(mesh)
+    try:
+        out = jax.jit(lambda q, k, v: fa_ops.flash_attention_sharded(
+            q, k, v, interpret=True))(q, k, v)
+    finally:
+        common.set_active_mesh(prev)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(fa_ref.naive_attention(q, k, v)),
+                               atol=2e-5)
 
 
 def test_blocked_attention_cross_ragged():
@@ -130,7 +182,7 @@ def test_paged_ref_matches_contiguous_gather():
 def test_newton_schulz_vs_ref(shape, dtype):
     m = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
     ref = newton_schulz_ref(m)
-    pal = ns_ops.newton_schulz(m, force="pallas")
+    pal = ns_ops.newton_schulz_pallas(m, interpret=True)
     tol = 3e-2 if dtype == jnp.bfloat16 else 5e-5
     np.testing.assert_allclose(np.asarray(pal, np.float32),
                                np.asarray(ref, np.float32), atol=tol)
@@ -138,7 +190,7 @@ def test_newton_schulz_vs_ref(shape, dtype):
 
 def test_newton_schulz_orthogonalizes():
     m = jax.random.normal(jax.random.PRNGKey(1), (64, 128))
-    y = ns_ops.newton_schulz(m, force="pallas")
+    y = ns_ops.newton_schulz_pallas(m, interpret=True)
     s = jnp.linalg.svd(y, compute_uv=False)
     assert float(s.max()) < 1.35 and float(s.min()) > 0.3
 
@@ -149,6 +201,24 @@ def test_tiled_matmul():
     out = ns_kernel.matmul(x, y, bm=128, bk=128, bn=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x @ y),
                                atol=1e-3, rtol=1e-4)
+
+
+def test_tiled_matmul_default_tiles_cover_every_k():
+    """K = 768 does not divide the default 512 k-tile: the tile shrinks to
+    one that divides, so no slice of the contraction is dropped."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (256, 768))
+    y = jax.random.normal(jax.random.PRNGKey(5), (768, 384))
+    out = ns_kernel.matmul(x, y, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(x @ y),
+                               atol=1e-3, rtol=1e-4)
+
+
+def test_newton_schulz_tiled_path_vs_ref():
+    """The large-matrix path (tiled matmuls) against the oracle."""
+    m = jax.random.normal(jax.random.PRNGKey(6), (128, 384))
+    out = ns_ops._ns_tiled(m, 5, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(newton_schulz_ref(m)), atol=5e-5)
 
 
 # ---------------------------------------------------------------------------

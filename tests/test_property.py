@@ -114,7 +114,10 @@ def test_softcap_bounded_and_monotone(cap, scale):
     x = jnp.linspace(-scale, scale, 101)
     y = softcap(x, cap)
     assert float(jnp.abs(y).max()) <= cap + 1e-5
-    assert bool(jnp.all(jnp.diff(y) >= -1e-6))
+    # Near saturation tanh moves in float32 ulps of 1.0, which the cap
+    # multiplies: adjacent outputs may dip by at most cap x one ulp.
+    ulp = float(np.finfo(np.float32).eps)
+    assert bool(jnp.all(jnp.diff(y) >= -cap * ulp))
 
 
 @SET

@@ -5,9 +5,7 @@ axis and int8/fp8 KV-page storage — so its contract is pinned here once:
 symmetric zero-point-free scales (always float32), ``axis=None`` scalar
 scales vs kept-dims per-axis scales that broadcast without reshapes,
 round-to-nearest error bounded by half a scale step (int8), fp8 cast
-saturation at +-448, and the ``--kv-dtype`` CLI name resolution
-(including the hard error when 'fp8' is requested on a jaxlib without
-float8 support — quantized serving must never silently widen).
+saturation at +-448, and the ``--kv-dtype`` CLI name resolution.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -15,8 +13,7 @@ import pytest
 
 from repro.core import quant
 
-needs_fp8 = pytest.mark.skipif(quant.fp8_dtype() is None,
-                               reason="jaxlib has no float8_e4m3fn")
+F8 = jnp.float8_e4m3fn
 
 
 def _rand(shape, seed=0, lo=-3.0, hi=3.0):
@@ -72,15 +69,13 @@ def test_dequantize_output_dtype():
     assert quant.dequantize(q, s, jnp.bfloat16).dtype == jnp.bfloat16
 
 
-@needs_fp8
 def test_fp8_roundtrip_and_saturation():
     """fp8 e4m3fn: 3 mantissa bits -> relative error <= ~2^-4 after the
     max-scaling; out-of-range values saturate at +-448 * scale instead of
     becoming inf."""
     x = _rand((32, 16), seed=2)
-    f8 = quant.fp8_dtype()
-    q, s = quant.quantize(x, axis=-1, dtype=f8)
-    assert q.dtype == jnp.dtype(f8) and s.dtype == jnp.float32
+    q, s = quant.quantize(x, axis=-1, dtype=F8)
+    assert q.dtype == jnp.dtype(F8) and s.dtype == jnp.float32
     deq = np.asarray(quant.dequantize(q, s))
     rel = np.abs(deq - x) / np.maximum(np.abs(x), 1e-3)
     assert rel.max() <= 0.07
@@ -94,9 +89,8 @@ def test_qmax_and_is_quantized():
     assert not quant.is_quantized(jnp.bfloat16)
     with pytest.raises(ValueError, match="not a quantized"):
         quant.qmax(jnp.float32)
-    if quant.fp8_dtype() is not None:
-        assert quant.qmax(quant.fp8_dtype()) == 448.0
-        assert quant.is_quantized(quant.fp8_dtype())
+    assert quant.qmax(F8) == 448.0
+    assert quant.is_quantized(F8)
 
 
 def test_resolve_kv_dtype_names():
@@ -106,7 +100,4 @@ def test_resolve_kv_dtype_names():
     assert quant.resolve_kv_dtype("int8") == jnp.int8
     with pytest.raises(ValueError, match="unknown kv_dtype"):
         quant.resolve_kv_dtype("int4")
-    if quant.fp8_dtype() is not None:
-        assert quant.resolve_kv_dtype("fp8") == jnp.dtype(quant.fp8_dtype())
-    # (when fp8 is unsupported the resolver raises instead of widening —
-    # exercised implicitly on jaxlibs without float8)
+    assert quant.resolve_kv_dtype("fp8") == jnp.dtype(F8)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import checkpointer as ckpt
 from repro.configs.base import ModelConfig, SSMConfig
@@ -270,7 +271,9 @@ def test_checkpoint_subtree_restore_for_serving(tmp_path):
 
 
 def _spec(shardings, name):
-    return tuple(shardings[name].spec)
+    """The leaf's PartitionSpec; compared against P(...) literals, which
+    normalizes a one-axis tuple entry ``("data",)`` to ``"data"``."""
+    return shardings[name].spec
 
 
 def test_cache_shardings_batch_and_model_dims():
@@ -278,7 +281,7 @@ def test_cache_shardings_batch_and_model_dims():
     specs = {"k": jax.ShapeDtypeStruct((3, 8, 24, 2, 16), jnp.float32)}
     sh = shd.cache_shardings(specs, mesh)
     # batch (dim 1) over 'data', longest remaining dim (seq=24) over 'model'
-    assert _spec(sh, "k") == (None, ("data",), "model", None, None)
+    assert _spec(sh, "k") == P(None, "data", "model", None, None)
 
 
 def test_cache_shardings_batch_over_pod_and_data():
@@ -294,7 +297,7 @@ def test_cache_shardings_indivisible_falls_back_to_replication():
     specs = {"odd": jax.ShapeDtypeStruct((3, 6, 5, 3), jnp.float32)}
     sh = shd.cache_shardings(specs, mesh)
     # 6 % 4 != 0 (batch), 5/3 % 2 != 0 (model): fully replicated, compiles
-    assert _spec(sh, "odd") == (None, None, None, None)
+    assert _spec(sh, "odd") == P(None, None, None, None)
 
 
 def test_cache_shardings_never_shards_superblock_axis():
@@ -304,4 +307,4 @@ def test_cache_shardings_never_shards_superblock_axis():
     sh = shd.cache_shardings(specs, mesh)
     spec = _spec(sh, "v")
     assert spec[0] is None
-    assert spec == (None, ("data",), "model", None)
+    assert spec == P(None, "data", "model", None)

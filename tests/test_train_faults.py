@@ -182,8 +182,14 @@ def test_crash_resume_byte_parity_expansion_window(tmp_path, clean_result, k):
     convention (periodic save labeled with the step it ran AFTER, so the
     resume re-ran that step: one batch trained twice)."""
     d = str(tmp_path)
+    crashed = run(ckpt_dir=d, faults=f"train.iter:{k + 1}:crash")
     with pytest.raises(CrashError):
-        run(ckpt_dir=d, faults=f"train.iter:{k + 1}:crash").run()
+        crashed.run()
+    # The crash unwinds the loop, not the process: the async checkpoint
+    # writer it orphaned may still be writing step 3.  Join it before the
+    # directory is read, or the resume races a half-written step — on a
+    # loaded machine k=4, one step after that save, lost the race.
+    crashed._ckptr.wait()
     assert ckpt.latest_step(d) is not None and ckpt.latest_step(d) <= k
     res = run(ckpt_dir=d).run()
     assert res.final_layers == 2
