@@ -73,12 +73,18 @@ class StragglerMonitor:
     rolling median.  On a real cluster the flag triggers the runbook action
     (drain + hot-spare swap); here it feeds logs/tests.
 
+    The trainer feeds it the ``train.dispatch`` span's seconds
+    (``observe``): under JAX's async dispatch that is the enqueue of the
+    step, which follows the device's step time only when back-pressure
+    blocks the enqueue (or the first call at a shape traces and compiles).
+    ``start``/``stop`` time a block themselves.
+
     ``hang_deadline_s`` adds a hard ceiling: a step that exceeds it raises
-    ``train.faults.HangError`` (a ``train.step`` FaultError) from ``stop``
-    instead of silently counting as slow — a stuck collective surfaces as
-    a fault the trainer's containment can log and move past, rather than
-    the loop stalling forever.  The measured ``dt`` is recorded in
-    ``last_dt`` before raising."""
+    ``train.faults.HangError`` (a ``train.step`` FaultError) from
+    ``observe`` instead of silently counting as slow — a stuck collective
+    surfaces as a fault the trainer's containment can log and move past,
+    rather than the loop stalling forever.  The measured ``dt`` is recorded
+    in ``last_dt`` before raising."""
 
     def __init__(self, window: int = 50, threshold: float = 2.0,
                  hang_deadline_s: Optional[float] = None):
@@ -94,7 +100,10 @@ class StragglerMonitor:
         self._t0 = time.perf_counter()
 
     def stop(self) -> Tuple[float, bool]:
-        dt = time.perf_counter() - self._t0
+        return self.observe(time.perf_counter() - self._t0)
+
+    def observe(self, dt: float) -> Tuple[float, bool]:
+        """Record one step's ``dt`` seconds: (dt, whether it was slow)."""
         self.last_dt = dt
         slow = False
         if len(self.times) >= 10:

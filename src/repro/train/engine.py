@@ -56,6 +56,7 @@ from repro.launch import mesh as mesh_lib
 from repro.models import common as model_common
 from repro.models import registry
 from repro.optim.base import make_optimizer
+from repro.spans import span
 from repro.train import faults as faults_lib
 from repro.train import steps as steps_lib
 
@@ -408,7 +409,6 @@ class ProgressiveTrainer:
             # ---- depth expansion at τ (paper's technique) ------------------
             if step in exp_steps and cur_layers < exp_steps[step].target_layers:
                 e = exp_steps[step]
-                save(step)                   # expansion boundary checkpoint
 
                 def expand():
                     plane.fire("train.expand")
@@ -422,11 +422,14 @@ class ProgressiveTrainer:
                     return expand_fn(params, opt_state, key), \
                         new_p_sh, new_os_sh
 
-                (params, opt_state), p_sh, os_sh = \
-                    self._retry("train.expand", expand)
-                cur_layers = e.target_layers
-                cur_cfg = model_cfg.with_depth(cur_layers)
-                train_step, eval_step = self._build_steps(cur_cfg, p_sh, os_sh)
+                with span("train.expand"):
+                    save(step)               # expansion boundary checkpoint
+                    (params, opt_state), p_sh, os_sh = \
+                        self._retry("train.expand", expand)
+                    cur_layers = e.target_layers
+                    cur_cfg = model_cfg.with_depth(cur_layers)
+                    train_step, eval_step = self._build_steps(cur_cfg, p_sh,
+                                                              os_sh)
                 history["expansion_steps"].append(step)
                 self.log_fn(f"[expand] step={step} -> {cur_layers} layers "
                             f"({e.init}, OS={e.opt_state_policy})")
@@ -439,8 +442,8 @@ class ProgressiveTrainer:
                 plane.fire("train.batch")
                 return self._place_batch(self.data.batch(step))
 
-            batch = self._retry("train.batch", fetch_batch)
-            monitor.start()
+            with span("train.fetch"):
+                batch = self._retry("train.batch", fetch_batch)
 
             def dispatch():
                 plane.fire("train.step")
@@ -450,9 +453,11 @@ class ProgressiveTrainer:
                                       jnp.float32(gnorm_ema))
                 return train_step(params, opt_state, batch, jnp.asarray(step))
 
-            params, opt_state, metrics = self._retry("train.step", dispatch)
+            with span("train.dispatch") as dispatched:
+                params, opt_state, metrics = self._retry("train.step",
+                                                         dispatch)
             try:
-                dt, slow = monitor.stop()
+                dt, slow = monitor.observe(dispatched.seconds)
             except faults_lib.FaultError as e:
                 # The hung step HAS run (buffers donated): record, move on.
                 history["hangs"].append(step)
