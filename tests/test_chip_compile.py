@@ -16,6 +16,7 @@ off around these compiles: an entry written for a described chip cannot be
 read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import configs as cfglib
-from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.flash_attention.kernel import _plan, flash_attention_tpu
 from repro.kernels.newton_schulz import kernel as ns_kernel
 from repro.kernels.newton_schulz import ops as ns_ops
 from repro.kernels.paged_attention.kernel import paged_attention_tpu
@@ -84,6 +85,81 @@ def test_flash_forward_backward_compiles(one_chip):
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
     # forward (recomputed residuals) + dq + dk/dv kernels
     assert hlo.count("tpu_custom_call") >= 3
+
+
+def _model_layout_grad_hlo(one_chip, H, KV, hd):
+    """HLO of the forward and backward of attention on q, k, v as the
+    model's projections give them, ``(B, S, heads * hd)``."""
+    def loss(q, k, v):
+        o = flash_attention_tpu(q.reshape(B, S, H, hd),
+                                k.reshape(B, S, KV, hd),
+                                v.reshape(B, S, KV, hd))
+        return jnp.sum(o.reshape(B, S, H * hd) ** 2)
+
+    shapes = [_struct((B, S, n * hd), jnp.float32, one_chip)
+              for n in (H, KV, KV)]
+    return _compile(jax.grad(loss, argnums=(0, 1, 2)), *shapes)
+
+
+def _kernel_calls(hlo):
+    """{kernel name: [operand and result shapes]} of the Pallas calls."""
+    calls = {}
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        name = re.search(r"flash_attention_(fwd|dq|dkv)", line).group(0)
+        result = line.split(" custom-call(")[0]
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}\}",
+                             line).group(1)
+        calls[name] = re.findall(r"f32\[[\d,]*\]", result + operands)
+    return calls
+
+
+def _copies(hlo):
+    """Result shapes of the copies and transposes in the HLO."""
+    return re.findall(r"= (f32\[[\d,]*\])\{[^}]*\} (?:copy|transpose)\(",
+                      hlo)
+
+
+def test_flash_gpt2_step_runs_on_the_model_layout(one_chip):
+    """At gpt2-12l widths the kernels read and write the model's (B, S, 768)
+    arrays and dense (B, 6, 2, S) statistics: no head-major transposes, no
+    (..., S, 1) statistics, no copy of an activation."""
+    H, hd = GPT2.num_heads, GPT2.head_dim
+    assert _plan((B, S, H, hd), (B, S, H, hd)) == ("lane_dense", 2)
+    hlo = _model_layout_grad_hlo(one_chip, H, H, hd)
+    calls = _kernel_calls(hlo)
+    assert set(calls) == {"flash_attention_fwd", "flash_attention_dq",
+                          "flash_attention_dkv"}
+    act, stat = f"f32[{B},{S},{H * hd}]", f"f32[{B},{H // 2},2,{S}]"
+    for name, shapes in calls.items():
+        assert act in shapes, name
+        assert set(shapes) <= {act, stat}, (name, shapes)
+    assert not any(c in (f"f32[{B},{S},{H},{hd}]", f"f32[{B},{H},{S},{hd}]",
+                         act) for c in _copies(hlo)), _copies(hlo)
+    assert not re.search(rf"f32\[[\d,]*,{S},1\]", hlo)
+
+
+def test_flash_gqa_hd128_compiles_lane_dense(one_chip):
+    """starcoder2-3b's attention (24 q heads, 2 kv heads of 128): one head
+    per lane block, GQA in the index map."""
+    cfg = cfglib.get_config("starcoder2-3b")
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    assert _plan((B, S, H, hd), (B, S, KV, hd)) == ("lane_dense", 1)
+    calls = _kernel_calls(_model_layout_grad_hlo(one_chip, H, KV, hd))
+    assert f"f32[{B},{S},{KV * hd}]" in calls["flash_attention_fwd"]
+    assert f"f32[{B},{S},{H * hd}]" in calls["flash_attention_dkv"]
+
+
+def test_flash_gqa_hd64_compiles_head_major(one_chip):
+    """The paper's llama3-0.3b testbed (16 q heads, 8 kv heads of 64): GQA
+    at hd 64 keeps the head-major path."""
+    cfg = cfglib.get_config("llama3-0.3b")
+    H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    assert _plan((B, S, H, hd), (B, S, KV, hd)) == ("head_major", 1)
+    calls = _kernel_calls(_model_layout_grad_hlo(one_chip, H, KV, hd))
+    assert f"f32[{B},{H},{S},{hd}]" in calls["flash_attention_dq"]
+    assert f"f32[{B},{H},1,{S}]" in calls["flash_attention_dq"]
 
 
 # ---------------------------------------------------------------------------

@@ -5,9 +5,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import configs as cfglib
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention import ref as fa_ref
-from repro.kernels.flash_attention.kernel import flash_attention_tpu
+from repro.kernels.flash_attention.kernel import _plan, flash_attention_tpu
 from repro.kernels.mamba_scan.kernel import selective_scan_tpu
 from repro.kernels.mamba_scan.ref import selective_scan_ref
 from repro.kernels.newton_schulz import kernel as ns_kernel
@@ -25,8 +26,25 @@ from repro.models import common
 # flash attention
 # ---------------------------------------------------------------------------
 
+# (H, KV, hd) -> the layout ``_plan`` must choose for it.
+FLASH_PLANS = {
+    (2, 2, 32): ("head_major", 1),    # 2 heads of 32: half a 128-lane block
+    (4, 2, 64): ("head_major", 1),    # GQA at hd 64
+    (4, 1, 16): ("head_major", 1),
+    (4, 2, 16): ("head_major", 1),
+    (2, 2, 16): ("head_major", 1),
+    (2, 1, 16): ("head_major", 1),
+    (4, 4, 64): ("lane_dense", 2),    # MHA at hd 64, as gpt2
+    (3, 3, 64): ("head_major", 1),    # odd head count at hd 64
+    (4, 2, 128): ("lane_dense", 1),   # GQA at hd 128, as starcoder2
+    (4, 4, 32): ("lane_dense", 4),
+}
+
+
 @pytest.mark.parametrize("S,H,KV,hd", [(64, 2, 2, 32), (128, 4, 2, 64),
-                                       (96, 4, 1, 16)])
+                                       (96, 4, 1, 16), (256, 4, 4, 64),
+                                       (256, 3, 3, 64), (256, 4, 2, 128),
+                                       (256, 4, 4, 32)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_flash_attention_shapes_dtypes(S, H, KV, hd, dtype):
     B = 2
@@ -34,8 +52,9 @@ def test_flash_attention_shapes_dtypes(S, H, KV, hd, dtype):
     q = jax.random.normal(ks[0], (B, S, H, hd), dtype)
     k = jax.random.normal(ks[1], (B, S, KV, hd), dtype)
     v = jax.random.normal(ks[2], (B, S, KV, hd), dtype)
+    assert _plan(q.shape, k.shape) == FLASH_PLANS[(H, KV, hd)]
     ref = fa_ref.naive_attention(q, k, v, causal=True, window=0)
-    pal = flash_attention_tpu(q, k, v, causal=True, block_q=32, block_k=32,
+    pal = flash_attention_tpu(q, k, v, causal=True, block_q=128, block_k=32,
                               interpret=True)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(pal, np.float32),
@@ -59,25 +78,41 @@ def test_flash_attention_masks(window, softcap, causal):
     np.testing.assert_allclose(np.asarray(pal), np.asarray(ref), atol=2e-5)
 
 
-@pytest.mark.parametrize("Sq,Sk,H,KV,causal,window,softcap", [
-    (64, 64, 4, 2, True, 0, 0.0),        # GQA causal
-    (128, 128, 2, 2, True, 16, 20.0),     # window + softcap
-    (64, 64, 2, 1, False, 0, 0.0),        # MQA, bidirectional
-    (16, 40, 2, 2, False, 0, 0.0),        # cross-attention, Sq != Sk
+def _grad_case(Sq, Sk, H, KV, causal, window, softcap, hd=16):
+    """The hd-16 cases keep the ids they had before hd was a column."""
+    return pytest.param(Sq, Sk, H, KV, causal, window, softcap, hd,
+                        id="-".join(map(str, (Sq, Sk, H, KV, causal, window,
+                                              softcap)))
+                        if hd == 16 else None)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,KV,causal,window,softcap,hd", [
+    _grad_case(64, 64, 4, 2, True, 0, 0.0),        # GQA causal
+    _grad_case(128, 128, 2, 2, True, 16, 20.0),     # window + softcap
+    _grad_case(64, 64, 2, 1, False, 0, 0.0),        # MQA, bidirectional
+    _grad_case(16, 40, 2, 2, False, 0, 0.0),        # cross-attention, Sq != Sk
+    _grad_case(256, 256, 4, 4, True, 0, 0.0, hd=64),      # MHA, lane-dense
+    _grad_case(256, 256, 3, 3, True, 0, 0.0, hd=64),      # odd H, head-major
+    _grad_case(256, 256, 4, 2, True, 0, 0.0, hd=128),     # GQA, lane-dense
+    _grad_case(256, 256, 4, 2, True, 0, 0.0, hd=64),      # GQA, head-major
+    _grad_case(384, 384, 4, 4, True, 100, 20.0, hd=64),   # window + softcap
+    _grad_case(128, 384, 4, 4, False, 0, 0.0, hd=64),     # Sq != Sk
 ])
-def test_flash_attention_grad_vs_ref(Sq, Sk, H, KV, causal, window, softcap):
+def test_flash_attention_grad_vs_ref(Sq, Sk, H, KV, causal, window, softcap,
+                                     hd):
     """The custom-VJP backward (Pallas dq and dk/dv kernels) against
     autodiff through the naive oracle."""
-    B, hd = 2, 16
+    B = 2
     ks = jax.random.split(jax.random.PRNGKey(3), 4)
     q = jax.random.normal(ks[0], (B, Sq, H, hd))
     k = jax.random.normal(ks[1], (B, Sk, KV, hd))
     v = jax.random.normal(ks[2], (B, Sk, KV, hd))
     do = jax.random.normal(ks[3], (B, Sq, H, hd))
+    assert _plan(q.shape, k.shape) == FLASH_PLANS[(H, KV, hd)]
     kw = dict(causal=causal, window=window, logit_softcap=softcap)
 
     def pal(q, k, v):
-        return jnp.sum(flash_attention_tpu(q, k, v, block_q=32, block_k=32,
+        return jnp.sum(flash_attention_tpu(q, k, v, block_q=128, block_k=32,
                                            interpret=True, **kw) * do)
 
     def ref(q, k, v):
@@ -87,6 +122,21 @@ def test_flash_attention_grad_vs_ref(Sq, Sk, H, KV, causal, window, softcap):
                          jax.grad(ref, (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("name,plan", [
+    ("gpt2-12l", ("lane_dense", 2)), ("whisper-base", ("lane_dense", 2)),
+    ("starcoder2-3b", ("lane_dense", 1)), ("qwen2-vl-2b", ("lane_dense", 1)),
+    ("yi-34b", ("lane_dense", 1)), ("gemma2-9b", ("lane_dense", 1)),
+    ("llama3-0.3b", ("head_major", 1)), ("mixtral-0.3b", ("head_major", 1)),
+])
+def test_flash_attention_plan_of_configs(name, plan):
+    """The layout each config's attention takes, as the kernel's docstring
+    lists it."""
+    cfg = cfglib.get_config(name)
+    q = (1, 1024, cfg.num_heads, cfg.head_dim)
+    k = (1, 1024, cfg.num_kv_heads, cfg.head_dim)
+    assert _plan(q, k) == plan
 
 
 def test_flash_attention_sharded_over_mesh():
