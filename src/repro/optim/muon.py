@@ -6,8 +6,9 @@ transfer across depth/width); every other leaf uses normalized SGD, with a
 *single* learning rate for both — exactly the paper's Muon-NSGD.
 
 Stacked super-block leaves (leading n_super axis from the layer scan) are
-orthogonalized per-layer via vmap over the leading axes, so progressive depth
-expansion leaves optimizer semantics unchanged.
+orthogonalized per-layer via vmap over the leading axes (one layer at a time
+on a multi-device mesh), so progressive depth expansion leaves optimizer
+semantics unchanged.
 """
 from __future__ import annotations
 
@@ -58,7 +59,10 @@ def orthogonalize(m: jax.Array, steps: int = 5) -> jax.Array:
 
     Routes through the Pallas kernel on TPU (repro.kernels.newton_schulz);
     on a multi-device mesh every device orthogonalizes the whole
-    (gathered) matrix, since the kernel cannot be partitioned.
+    (gathered) matrix, since the kernel cannot be partitioned.  There a
+    stack is orthogonalized one matrix at a time (``lax.map``), so only one
+    gathered matrix and its result are live: a whole gathered stack of
+    3072 x 12288 layers would not fit beside the sharded state.
     """
     from repro.kernels.newton_schulz import ops as ns_ops
     from repro.models import common
@@ -70,6 +74,10 @@ def orthogonalize(m: jax.Array, steps: int = 5) -> jax.Array:
 
     if jax.default_backend() == "tpu":
         run = common.kernel_shard_map(run, (P(),), P())
+    mesh = common.get_active_mesh()
+    if mesh is not None and mesh.size > 1 and m.ndim > 2:
+        x = m.reshape((-1,) + m.shape[-2:])
+        return jax.lax.map(run, x).reshape(m.shape)
     return run(m)
 
 

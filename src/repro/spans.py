@@ -1,7 +1,8 @@
 """Host spans of the program, on the device trace's clock while profiling.
 
 ``with span("train.fetch") as s: ...`` times its body on
-``time.perf_counter`` and leaves the seconds in ``s.seconds``, always.
+``time.perf_counter`` and leaves the seconds in ``s.seconds``, always;
+``last(name)`` gives them too, for the latest span of that name to close.
 While a ``jax.profiler`` session runs, a span also
 
 * opens a ``jax.profiler.TraceAnnotation`` of its name, so it lies beside
@@ -9,7 +10,7 @@ While a ``jax.profiler`` session runs, a span also
 * appends ``(name, parent, start_ns, end_ns, attrs)`` to a bounded
   in-memory log (``log()``), times on ``time.perf_counter_ns``, ``parent``
   the position in ``log()`` of the innermost recorded span open on this
-  thread (``None`` for none).
+  thread (``None`` for none); ``s.note(key=value)`` adds to its ``attrs``.
 
 JAX's compile phases of a jit's first call are logged too, as children of
 the span open on the compiling thread: ``jax.trace`` (Python to jaxpr),
@@ -19,9 +20,9 @@ gives it; ``jax.compile`` also says whether that cache served it
 (``cache_hit``).  An inner jit is traced inside its outer one, so
 ``jax.*`` spans overlap: their union, not their sum, is the compile time.
 
-Nothing is recorded outside a profiler session, nor for a span opened
-before the session started: a span then costs two clock readings and one
-check of the profiler.
+Nothing is logged outside a profiler session, nor for a span opened
+before the session started: a span then costs two clock readings, one
+check of the profiler and one entry of the ``last`` table.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _lock = threading.Lock()
 _records: collections.deque = collections.deque(maxlen=MAX_RECORDS)
 _appended = 0           # records ever appended; a record's id is its number
+_last: dict = {}        # name -> seconds of the latest span of it to close
 _listening = False
 
 
@@ -116,15 +118,28 @@ class span:
             _thread.open.append(self._rec[5])
         return self
 
+    def note(self, **attrs):
+        """Add ``attrs`` to the span's logged record; nothing when it is not
+        logged."""
+        if self._rec is not None:
+            self._rec[4].update(attrs)
+
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         self.seconds = (t1 - self._t0) / 1e9
+        _last[self.name] = self.seconds
         if self._rec is not None:
             self._rec[3] = t1
             _thread.open.pop()
             self._ann.__exit__(*exc)
             self._ann = self._rec = None
         return False
+
+
+def last(name: str):
+    """Seconds of the latest span called ``name`` to close, logged or not;
+    None before one has."""
+    return _last.get(name)
 
 
 def log() -> list:

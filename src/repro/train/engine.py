@@ -61,6 +61,12 @@ from repro.train import faults as faults_lib
 from repro.train import steps as steps_lib
 
 
+def _bytes_on(device, tree) -> int:
+    """Bytes of ``tree``'s arrays that lie on ``device``."""
+    return sum(s.data.nbytes for x in jax.tree.leaves(tree)
+               for s in x.addressable_shards if s.device == device)
+
+
 @dataclasses.dataclass
 class TrainResult:
     history: Dict[str, List]
@@ -343,7 +349,11 @@ class ProgressiveTrainer:
         if meta is None:
             cur_cfg = model_cfg.with_depth(cur_layers)
             p_sh, os_sh, _, _ = self._state_shardings(cur_cfg)
-            params, opt_state = self._init_state(cur_cfg, p_sh, os_sh)
+            with span("train.init") as init:
+                params, opt_state = self._init_state(cur_cfg, p_sh, os_sh)
+                jax.block_until_ready((params, opt_state))
+                init.note(state_bytes=_bytes_on(self.mesh.devices.flat[0],
+                                                (params, opt_state)))
 
         train_step, eval_step = self._build_steps(cur_cfg, p_sh, os_sh)
         monitor = StragglerMonitor(hang_deadline_s=self.hang_deadline_s)

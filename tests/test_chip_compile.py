@@ -20,8 +20,10 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro import configs as cfglib
 from repro.kernels.flash_attention.kernel import _plan, flash_attention_tpu
@@ -191,12 +193,45 @@ def test_newton_schulz_compiles(one_chip, shape):
     assert "tpu_custom_call" in hlo
 
 
-@pytest.mark.parametrize("shape", [(768, 768), (768, 3072)])
+@pytest.mark.parametrize("shape", [(768, 768), (768, 3072),
+                                   (15, 3072, 12288), (15, 12288, 3072)])
 def test_newton_schulz_compiles_over_a_layer_stack(one_chip, shape):
-    """Muon vmaps the kernel over the scanned layer stack."""
-    x = _struct((12,) + shape, jnp.float32, one_chip)
+    """Muon vmaps the kernel over the scanned layer stack: 12 layers of
+    ``gpt2-12l``, or (three sizes given) 15 layers of ``gpt2-60l``'s MLP."""
+    x = _struct(shape if len(shape) == 3 else (12,) + shape, jnp.float32,
+                one_chip)
     _compile(jax.vmap(lambda m: ns_ops.newton_schulz_pallas(
         m, interpret=False)), x)
+
+
+@pytest.fixture(scope="module")
+def two_by_two(one_chip):
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    return Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+
+
+def test_muon_orthogonalizes_a_sharded_stack_one_matrix_at_a_time(
+        two_by_two, monkeypatch):
+    """On the 2x2 mesh Muon gathers and orthogonalizes one matrix of a
+    15-layer stack of ``gpt2-60l``'s 3072 x 12288 MLP at a time: the
+    kernel runs in a loop, and the program's temporaries stay far under
+    one gathered stack (2.26 GB)."""
+    from repro.models import common
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    prev = common.get_active_mesh()
+    common.set_active_mesh(two_by_two)
+    try:
+        x = _struct((15, 3072, 12288), jnp.float32,
+                    NamedSharding(two_by_two, P(None, "data", "model")))
+        compiled = jax.jit(lambda m: muon.orthogonalize(m)).lower(x).compile()
+    finally:
+        common.set_active_mesh(prev)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and " while(" in hlo
+    stack = 15 * 3072 * 12288 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < stack / 2
 
 
 def test_newton_schulz_fused_path_compiles_at_its_vmem_limit(one_chip):

@@ -146,6 +146,52 @@ def test_muon_stacked_leaves_per_layer():
         assert float(s.max()) < 1.4
 
 
+def test_muon_orthogonalize_on_one_device_is_the_vmap_over_the_stack():
+    """Without a mesh, or on a one-device mesh, ``orthogonalize`` is the
+    same function it always was: Newton-Schulz vmapped over the stack."""
+    from repro.kernels.newton_schulz import ops as ns_ops
+    from repro.models import common
+    from repro.optim.muon import orthogonalize
+
+    def vmapped(m, steps=5):
+        x = m.reshape((-1,) + m.shape[-2:])
+        y = jax.vmap(lambda a: ns_ops.newton_schulz(a, steps=steps))(x)
+        return y.reshape(m.shape)
+
+    prev = common.get_active_mesh()
+    try:
+        for mesh in (None, mesh_lib.single_device_mesh()):
+            common.set_active_mesh(mesh)
+            for shape in ((3, 16, 32), (16, 32)):
+                m = jnp.ones(shape)
+                # a fresh function each time: jit caches traces by function
+                assert str(jax.make_jaxpr(lambda x: orthogonalize(x))(m)) \
+                    == str(jax.make_jaxpr(vmapped)(m))
+    finally:
+        common.set_active_mesh(prev)
+
+
+def test_muon_orthogonalize_layer_by_layer_on_a_mesh_equals_one_device():
+    """On a 2x2 mesh a stack is orthogonalized one matrix at a time
+    (``lax.map``) and gives what the one-device path gives."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro.models import common
+    from repro.optim.muon import orthogonalize
+    m = jax.random.normal(jax.random.PRNGKey(1), (3, 32, 64))
+    want = jax.jit(lambda x: orthogonalize(x))(m)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    prev = common.get_active_mesh()
+    common.set_active_mesh(mesh)
+    try:
+        assert "scan" in str(jax.make_jaxpr(lambda x: orthogonalize(x))(m))
+        got = jax.jit(lambda x: orthogonalize(x))(jax.device_put(
+            m, NamedSharding(mesh, P(None, "data", "model"))))
+    finally:
+        common.set_active_mesh(prev)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+
+
 def test_grad_clip():
     from repro.optim.base import clip_by_global_norm
     g = {"a": jnp.ones((10,)) * 100.0}

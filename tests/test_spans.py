@@ -144,3 +144,51 @@ def test_trainer_compiles_only_where_the_depth_is_new(grows, log_dir):
     assert {r[0] for r in phases} == JAX_PHASES
     assert res.history["step_time"][data.at:] == \
         [(log[i][3] - log[i][2]) / 1e9 for i in dispatch]
+
+
+def test_last_seconds_always_and_notes_only_while_logged(log_dir):
+    with spans.span("test.noted") as s:
+        s.note(k=1)
+    assert spans.log() == [] and spans.last("test.noted") == s.seconds
+    with jax.profiler.trace(log_dir):
+        with spans.span("test.noted") as t:
+            t.note(k=2)
+    assert spans.log()[-1][0] == "test.noted" and spans.log()[-1][4] == \
+        {"k": 2}
+    assert spans.last("test.noted") == t.seconds
+    assert spans.last("test.never") is None
+
+
+@pytest.mark.parametrize("profiled", [False, True])
+def test_trainer_init_span_once_a_run_with_its_state_bytes(profiled,
+                                                           log_dir):
+    """``train.init`` covers the creation of the weights and the optimizer
+    state: logged once a run, with the bytes of that state on the mesh's
+    first device, only while a profiler runs; its seconds always."""
+    tc = TrainConfig(total_steps=2, seq_len=16, global_batch=4,
+                     source_layers=2,
+                     optimizer=OptimizerConfig(name="adamw",
+                                               learning_rate=1e-3),
+                     schedule=ScheduleConfig(name="constant"),
+                     eval_every=10_000, log_every=1)
+    data = SyntheticLM(DataConfig(vocab_size=CFG.vocab_size, seq_len=16,
+                                  global_batch=4))
+    trainer = ProgressiveTrainer(CFG, tc, data=data, eval_batches=[],
+                                 log_fn=lambda *a: None)
+    if profiled:
+        jax.profiler.start_trace(log_dir)
+    try:
+        res = trainer.run()
+    finally:
+        if profiled:
+            jax.profiler.stop_trace()
+    inits = [r for r in spans.log() if r[0] == "train.init"]
+    assert spans.last("train.init") > 0
+    if not profiled:
+        assert inits == []
+        return
+    assert len(inits) == 1 and inits[0][1] is None
+    state = jax.tree.leaves((res.params, res.opt_state))
+    assert inits[0][4] == {"state_bytes": sum(x.nbytes for x in state)}
+    assert (inits[0][3] - inits[0][2]) / 1e9 == pytest.approx(
+        spans.last("train.init"))
