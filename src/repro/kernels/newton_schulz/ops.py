@@ -30,12 +30,13 @@ def _pad_to(x, mult: int = 128):
 
 
 def _ns_tiled(x: jax.Array, steps: int, interpret: bool) -> jax.Array:
-    """NS via tiled Pallas matmuls for matrices too large to fuse."""
+    """NS via tiled Pallas matmuls for matrices too large to fuse; the Gram
+    matrix x·xᵀ is read from x itself (``transpose_rhs``)."""
     a, b, c = NS_COEFFS
     mm = functools.partial(K.matmul, interpret=interpret)
     x = x / (jnp.linalg.norm(x) + 1e-7)
     for _ in range(steps):
-        gram = mm(x, x.T)
+        gram = mm(x, x, transpose_rhs=True)
         poly = b * gram + c * mm(gram, gram)
         x = a * x + mm(poly, x)
     return x

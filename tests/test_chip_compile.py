@@ -185,7 +185,7 @@ def test_muon_shapes_cover_the_model():
 
 
 @pytest.mark.parametrize("shape", [(768, 768), (768, 3072), (3072, 768),
-                                   (50304, 768)])
+                                   (50304, 768), (3072, 3072), (50304, 3072)])
 def test_newton_schulz_compiles(one_chip, shape):
     x = _struct(shape, jnp.float32, one_chip)
     hlo = _compile(lambda m: ns_ops.newton_schulz_pallas(m, interpret=False),
@@ -232,6 +232,23 @@ def test_muon_orthogonalizes_a_sharded_stack_one_matrix_at_a_time(
     assert "tpu_custom_call" in hlo and " while(" in hlo
     stack = 15 * 3072 * 12288 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < stack / 2
+
+
+@pytest.mark.parametrize("M,K,N,transpose_rhs", [
+    (3072, 12288, 3072, True), (3072, 3072, 12288, False),
+    (3072, 3072, 3072, False), (3072, 50304, 3072, True),
+    (3072, 3072, 50304, False), (768, 50304, 768, True),
+    (768, 768, 50304, False)])
+def test_newton_schulz_matmul_compiles_at_its_planned_tiles(
+        one_chip, M, K, N, transpose_rhs):
+    """Every tiled product of ``gpt2-60l``'s and the embeddings' Newton–
+    Schulz compiles at the tiles ``_plan`` gives, under the VMEM limit the
+    kernel states, partial last blocks (N = 50304) included."""
+    x = _struct((M, K), jnp.float32, one_chip)
+    y = _struct((N, K) if transpose_rhs else (K, N), jnp.float32, one_chip)
+    hlo = _compile(lambda a, b: ns_kernel.matmul(
+        a, b, transpose_rhs=transpose_rhs, interpret=False), x, y)
+    assert "tpu_custom_call" in hlo
 
 
 def test_newton_schulz_fused_path_compiles_at_its_vmem_limit(one_chip):

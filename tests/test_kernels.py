@@ -271,6 +271,74 @@ def test_newton_schulz_tiled_path_vs_ref():
                                np.asarray(newton_schulz_ref(m)), atol=5e-5)
 
 
+def _muon_products():
+    """Every tiled product of the Muon matrices of ``gpt2-12l`` and
+    ``gpt2-60l`` (sides 768, 3072, 12288 and the 50304 vocabulary), each
+    matrix in both orientations: NS works on x with its short side n
+    first, and forms x·xᵀ (n, m, n), its square (n, n, n) and poly·x
+    (n, n, m)."""
+    sides = [(768, 768), (768, 3072), (768, 50304), (3072, 3072),
+             (3072, 12288), (3072, 50304)]
+    out = []
+    for a, b in sides:
+        for shape in {(a, b), (b, a)}:
+            n, m = sorted(shape)
+            for kind, mkn in (("gram", (n, m, n)), ("square", (n, n, n)),
+                              ("poly_x", (n, n, m))):
+                out.append(pytest.param(mkn, id=f"{shape[0]}x{shape[1]}-"
+                                        f"{kind}"))
+    return out
+
+
+@pytest.mark.parametrize("mkn", _muon_products())
+def test_matmul_plan(mkn):
+    """The tiled kernel's blocks: bk divides K, blocks are lane-aligned and
+    cover M and N with less than 128 wasted per block, the blocks bring at
+    least the v5e ridge's 240 FLOP per fetched byte wherever M and N are
+    at least 1024, and their VMEM fits the limit the kernel asks for."""
+    M, K, N = mkn
+    bm, bk, bn = ns_kernel._plan(M, K, N)
+    assert K % bk == 0 and bk % 128 == 0
+    for dim, b in ((M, bm), (N, bn)):
+        assert b % 128 == 0
+        blocks = -(-dim // b)
+        assert blocks * b - dim < 128 * blocks
+    if M >= 1024 and N >= 1024:
+        assert bm * bn / (2 * (bm + bn)) >= 240
+    assert ns_kernel.matmul_vmem_bytes(bm, bk, bn) <= \
+        ns_kernel.MATMUL_VMEM_LIMIT
+
+
+@pytest.mark.parametrize("M,K,N,transpose_rhs", [
+    (256, 768, 2176, False),     # N = 17·128: a partial last block
+    (2176, 768, 2176, True),     # the Gram form, x·xᵀ, partial in M and N
+])
+def test_tiled_matmul_planned_tiles(M, K, N, transpose_rhs):
+    """At its planned tiles the kernel computes x @ y (or x @ x.T), with
+    edge blocks whose rows and columns past the array are discarded."""
+    x = jax.random.normal(jax.random.PRNGKey(7), (M, K))
+    if transpose_rhs:
+        y, want = x, x @ x.T
+    else:
+        y = jax.random.normal(jax.random.PRNGKey(8), (K, N))
+        want = x @ y
+    bm, _, bn = ns_kernel._plan(M, K, N)
+    assert N % bn and (not transpose_rhs or M % bm)
+    out = ns_kernel.matmul(x, y, transpose_rhs=transpose_rhs, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-3, rtol=1e-4)
+
+
+def test_newton_schulz_tiled_path_spans_blocks_vs_ref():
+    """The tiled path at a shape of several blocks in every dimension of
+    every product (x 2176 × 2304: two blocks, the last partial, in the
+    Gram's M and N, nine in its K), against the oracle."""
+    m = jax.random.normal(jax.random.PRNGKey(9), (2176, 2304))
+    out = ns_ops._ns_tiled(m, 5, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(newton_schulz_ref(m)), atol=5e-5)
+
+
 # ---------------------------------------------------------------------------
 # rwkv6 wkv
 # ---------------------------------------------------------------------------
