@@ -130,7 +130,8 @@ def paged_attention_decode_deferred_ref(q, k_pages, v_pages, k_new, v_new,
     into the replicated pool, which costs one collective per layer per
     step on data-parallel meshes.  The caller commits (k_new, v_new) to
     the pool once per step, batched across every layer of the scan
-    (``transformer.lm_decode_step``).  The attention input is byte-
+    (``transformer._commit_paged_writes``, called by
+    ``transformer.lm_decode_step``).  The attention input is byte-
     identical to the contiguous ``attn_decode``'s cache-after-write, so
     parity holds by construction.  Quantized storage: pass the QUANTIZE-
     THEN-DEQUANTIZE round-tripped new K/V so the dense-selected token

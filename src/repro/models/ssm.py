@@ -118,12 +118,12 @@ def mamba_decode(p, cfg: ModelConfig, x: jax.Array, state) -> Tuple[jax.Array, d
     any batch slot without touching live rows.
 
     Contract (speculative decoding): this function is the single source of
-    truth for the recurrent step.  ``transformer._verify_layer`` replays it
-    token-by-token under ``lax.scan`` from the pre-round state (collecting
-    per-step state checkpoints for the rollback index-select), and the
-    draft loop's (γ+2)-deep checkpoint ring snapshots its outputs — so
-    spec-vs-solo byte parity holds because both paths run these exact
-    ops."""
+    truth for the recurrent step.  ``transformer._replay`` replays it, as
+    the mixer of ``transformer._block``, token-by-token under ``lax.scan``
+    from the pre-round state (collecting per-step state checkpoints for
+    the rollback index-select), and the draft loop's (γ+2)-deep
+    checkpoint ring snapshots its outputs — so spec-vs-solo byte parity
+    holds because both paths run these exact ops."""
     B = x.shape[0]
     d_inner, dt_rank, d_state, d_conv = mamba_dims(cfg)
     xs, z = _mamba_project(p, cfg, x)                      # (B,1,d_inner)

@@ -18,8 +18,7 @@ Zero-layer models (`n_super == 0`) skip the scan entirely: the model is
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Callable, Literal, NamedTuple, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -96,48 +95,125 @@ def num_superblocks(params) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Forward (train / prefill)
+# One layer body
 # ---------------------------------------------------------------------------
 
 
-def _apply_layer(lp, cfg: ModelConfig, i: int, x, positions):
-    """One layer, full-sequence.  Returns (x, aux_losses)."""
+class Mixers(NamedTuple):
+    """An entry point's token mixers, one per block kind.  ``attn(p, cfg, h,
+    state, window)`` and ``mamba`` / ``rwkv_time`` / ``rwkv_channel(p, cfg,
+    h, state)`` each return ``(y, state)``, threading the layer's cache or
+    carry.  ``per_token``: recurrent layers are given a multi-token chunk
+    and one-token mixers, so :func:`_replay` runs the block per token."""
+    attn: Callable
+    mamba: Callable
+    rwkv_time: Callable
+    rwkv_channel: Callable
+    per_token: bool = False
+
+
+def _block(lp, cfg: ModelConfig, i: int, x, mix: Mixers, state):
+    """Layer ``i`` of a super-block, for every entry point: its block
+    kind's residual structure around the entry point's token mixers.
+
+      attn:  norm -> mixer -> residual -> norm -> MLP or MoE -> residual
+      mamba: norm -> mixer -> residual (-> norm -> MoE -> residual)
+      rwkv:  norm -> time mix -> residual -> norm -> channel mix -> residual
+
+    Returns (x, state, MoE aux loss); inference drops the loss."""
     kind = cfg.layer_kind(i)
+    if kind not in ("attn", "mamba", "rwkv"):
+        raise ValueError(kind)
+    if kind != "attn" and mix.per_token:
+        x, state = _replay(lp, cfg, i, x, mix._replace(per_token=False),
+                           state)
+        return x, state, jnp.zeros((), jnp.float32)
     aux = jnp.zeros((), jnp.float32)
+    h = apply_norm(lp["ln1"], x, cfg.norm)
     if kind == "attn":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        x = x + attn.attn_apply(lp["attn"], cfg, h, positions,
-                                window=cfg.layer_window(i))
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        if cfg.layer_is_moe(i):
-            y, a = mlp_mod.moe_apply(lp["moe"], cfg, h)
-            aux = aux + a["aux_loss"] + a["router_zloss"]
-        else:
-            y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
-        x = x + y
+        y, state = mix.attn(lp["attn"], cfg, h, state, cfg.layer_window(i))
     elif kind == "mamba":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        x = x + ssm_mod.mamba_apply(lp["mamba"], cfg, h)
-        if cfg.layer_is_moe(i):
-            h = apply_norm(lp["ln2"], x, cfg.norm)
-            y, a = mlp_mod.moe_apply(lp["moe"], cfg, h)
-            aux = aux + a["aux_loss"] + a["router_zloss"]
-            x = x + y
-    elif kind == "rwkv":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        x = x + ssm_mod.rwkv_time_mix(lp["rwkv_tm"], cfg, h)
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        x = x + ssm_mod.rwkv_channel_mix(lp["rwkv_tm"], cfg, h)
-    x = maybe_shard(x, P(("pod", "data"), "model", None))
-    return x, aux
+        y, state = mix.mamba(lp["mamba"], cfg, h, state)
+    else:
+        y, state = mix.rwkv_time(lp["rwkv_tm"], cfg, h, state)
+    x = x + y
+    if kind == "mamba" and not cfg.layer_is_moe(i):
+        return x, state, aux
+    h = apply_norm(lp["ln2"], x, cfg.norm)
+    if kind == "rwkv":
+        y, state = mix.rwkv_channel(lp["rwkv_tm"], cfg, h, state)
+    elif cfg.layer_is_moe(i):
+        y, a = mlp_mod.moe_apply(lp["moe"], cfg, h)
+        aux = aux + a["aux_loss"] + a["router_zloss"]
+    else:
+        y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
+    return x + y, state, aux
 
 
-def _apply_superblock(sb, cfg: ModelConfig, x, positions):
+def _replay(lp, cfg: ModelConfig, i: int, x, mix: Mixers, state):
+    """Recurrent layer ``i`` over a (B, C) chunk, one token at a time:
+    ``_block`` with one-token mixers under ``lax.scan`` — the exact
+    per-token decode math, so the replay is bitwise identical to sequential
+    decode.  The state after every token goes into ``pending["states"]``:
+    a (C+1)-deep checkpoint ring (entry 0 = the pre-round state) from which
+    :func:`lm_spec_commit` rewinds each row to its accepted length with one
+    index-select, O(γ·state) memory per layer."""
+    def step(s, xt):
+        xt, s, _ = _block(lp, cfg, i, xt[:, None, :], mix, s)
+        return s, (xt[:, 0], s)
+    _, (xs, states) = jax.lax.scan(step, state, jnp.moveaxis(x, 1, 0))
+    ring = jax.tree.map(
+        lambda s0, ss: jnp.concatenate([s0[None].astype(ss.dtype), ss], 0),
+        state, states)
+    return jnp.moveaxis(xs, 0, 1), {"pending": {"states": ring}}
+
+
+# ---------------------------------------------------------------------------
+# The stack, the embedding and the head
+# ---------------------------------------------------------------------------
+
+_ROWS = P(("pod", "data"), None, None)
+_LAYER_OUT = P(("pod", "data"), "model", None)
+_LOGITS = P(("pod", "data"), None, "model")
+
+# Activation checkpointing of the layer scan: off, or on with a policy —
+# True / "nothing" saves nothing (full recompute), "dots" saves matmul
+# outputs (less recompute, more live memory).
+Remat = Union[bool, Literal["nothing", "dots"]]
+
+
+def _stack(params, cfg: ModelConfig, x, mix: Mixers, state=None,
+           remat: Remat = False, shard: bool = True):
+    """Every layer, as one ``lax.scan`` over the super-blocks of
+    ``params["blocks"]`` together with the per-layer ``state`` tree
+    (caches or carries with the leading n_super axis; None for training).
+    ``shard`` constrains each layer's output to the activation layout.
+    Returns (x, state, summed MoE aux loss)."""
     aux = jnp.zeros((), jnp.float32)
-    for i in range(cfg.pattern_period):
-        x, a = _apply_layer(sb[f"layer{i}"], cfg, i, x, positions)
-        aux = aux + a
-    return x, aux
+    if num_superblocks(params) == 0:
+        return x, state, aux
+
+    def scan_fn(carry, xs):
+        (x, aux), (sb, state_sb) = carry, xs
+        sb_aux = jnp.zeros((), jnp.float32)
+        new_state = {}
+        for i in range(cfg.pattern_period):
+            name = f"layer{i}"
+            x, new_state[name], a = _block(
+                sb[name], cfg, i, x, mix,
+                None if state_sb is None else state_sb[name])
+            if shard:
+                x = maybe_shard(x, _LAYER_OUT)
+            sb_aux = sb_aux + a
+        return (x, aux + sb_aux), new_state
+    if remat:
+        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                  if remat == "dots"
+                  else jax.checkpoint_policies.nothing_saveable)
+        scan_fn = jax.checkpoint(scan_fn, policy=policy)
+    (x, aux), state = jax.lax.scan(scan_fn, (x, aux),
+                                   (params["blocks"], state))
+    return x, state, aux
 
 
 def embed_tokens(params, cfg: ModelConfig, tokens, embeds=None, offset=0):
@@ -152,48 +228,63 @@ def embed_tokens(params, cfg: ModelConfig, tokens, embeds=None, offset=0):
     return x
 
 
-def _positions_for(cfg: ModelConfig, B, S):
-    pos = jnp.arange(S)[None, :]
+def _embed_at(params, cfg: ModelConfig, tokens, pos):
+    """Embedding of tokens (B, C) at per-row absolute positions pos (B, C)."""
+    x = params["embed"][tokens]
+    if cfg.position == "absolute":
+        x = x + params["pos_embed"][pos]
+    return x
+
+
+def _positions(cfg: ModelConfig, pos, B):
+    """Position ids of the attention layers from pos (1 or B, S)."""
+    S = pos.shape[1]
     if cfg.position == "mrope":
         return jnp.broadcast_to(pos[None], (3, B, S))      # text-only stub ids
     return jnp.broadcast_to(pos, (B, S))
 
 
-def lm_apply(params, cfg: ModelConfig, tokens, embeds=None, positions=None,
-             remat: bool = False) -> Tuple[jax.Array, jax.Array]:
-    """Returns (logits (B, S_total, V), aux_loss scalar)."""
+def _embed_prompt(params, cfg: ModelConfig, tokens, embeds, positions):
+    """Embedding and position ids of whole prompts at positions 0..S-1."""
     x = embed_tokens(params, cfg, tokens, embeds)
     B, S = x.shape[0], x.shape[1]
     if positions is None:
-        positions = _positions_for(cfg, B, S)
-    x = maybe_shard(x, P(("pod", "data"), None, None))
-    n_super = num_superblocks(params)
-    aux = jnp.zeros((), jnp.float32)
-    if n_super > 0:
-        body = functools.partial(_apply_superblock, cfg=cfg, positions=positions)
+        positions = _positions(cfg, jnp.arange(S)[None, :], B)
+    return maybe_shard(x, _ROWS), positions
 
-        def scan_fn(carry, sb):
-            x, aux = carry
-            x, a = body(sb, x=x)
-            return (x, aux + a), None
-        if remat:
-            # remat policy knob (§Perf): True/'nothing' recomputes everything
-            # inside each super-block; 'dots' saves matmul outputs (less
-            # recompute, more live memory).
-            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                      if remat == "dots"
-                      else jax.checkpoint_policies.nothing_saveable)
-            scan_fn = jax.checkpoint(scan_fn, policy=policy)
-        (x, aux), _ = jax.lax.scan(scan_fn, (x, aux), params["blocks"])
+
+def _head(params, cfg: ModelConfig, x):
+    """Final norm -> tied or untied LM head -> logit softcap."""
     x = apply_norm(params["final_norm"], x, cfg.norm)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap(x @ head, cfg.final_logit_softcap)
-    logits = maybe_shard(logits, P(("pod", "data"), None, "model"))
-    return logits, aux
+    return softcap(x @ head, cfg.final_logit_softcap)
+
+
+# ---------------------------------------------------------------------------
+# Forward (train)
+# ---------------------------------------------------------------------------
+
+
+def _train_mixers(positions) -> Mixers:
+    return Mixers(
+        attn=lambda p, cfg, h, s, window: (
+            attn.attn_apply(p, cfg, h, positions, window=window), s),
+        mamba=lambda p, cfg, h, s: (ssm_mod.mamba_apply(p, cfg, h), s),
+        rwkv_time=lambda p, cfg, h, s: (ssm_mod.rwkv_time_mix(p, cfg, h), s),
+        rwkv_channel=lambda p, cfg, h, s: (
+            ssm_mod.rwkv_channel_mix(p, cfg, h), s))
+
+
+def lm_apply(params, cfg: ModelConfig, tokens, embeds=None, positions=None,
+             remat: Remat = False) -> Tuple[jax.Array, jax.Array]:
+    """Returns (logits (B, S_total, V), aux_loss scalar)."""
+    x, positions = _embed_prompt(params, cfg, tokens, embeds, positions)
+    x, _, aux = _stack(params, cfg, x, _train_mixers(positions), remat=remat)
+    return maybe_shard(_head(params, cfg, x), _LOGITS), aux
 
 
 def lm_loss(params, cfg: ModelConfig, tokens, labels, embeds=None,
-            mask=None, remat: bool = False):
+            mask=None, remat: Remat = False):
     logits, aux = lm_apply(params, cfg, tokens, embeds=embeds, remat=remat)
     if embeds is not None:                  # loss on the text tail only
         logits = logits[:, embeds.shape[1]:]
@@ -202,30 +293,38 @@ def lm_loss(params, cfg: ModelConfig, tokens, labels, embeds=None,
 
 
 # ---------------------------------------------------------------------------
-# Decode (single token, stacked caches)
+# Serving state: caches and carries
 # ---------------------------------------------------------------------------
+
+
+def _layer_states(params, cfg: ModelConfig, batch: int, attn_state):
+    """Per-layer serving state mirroring the super-block stack (leading
+    n_super axis): ``attn_state(window)`` for attention layers, the O(1)
+    recurrent state of ``batch`` rows for mamba/rwkv layers."""
+    n_super = num_superblocks(params)
+    if n_super == 0:
+        return {}
+
+    def one_layer(i):
+        kind = cfg.layer_kind(i)
+        if kind == "attn":
+            return attn_state(cfg.layer_window(i))
+        if kind == "mamba":
+            return ssm_mod.mamba_init_state(cfg, batch)
+        if kind == "rwkv":
+            return ssm_mod.rwkv_init_state(cfg, batch)
+        raise ValueError(kind)
+
+    one = {f"layer{i}": one_layer(i) for i in range(cfg.pattern_period)}
+    return jax.tree.map(
+        lambda x: jnp.broadcast_to(x, (n_super,) + x.shape).copy(), one)
 
 
 def lm_init_cache(params, cfg: ModelConfig, batch_size: int, max_len: int,
                   dtype=jnp.bfloat16):
     """Cache pytree mirroring the super-block stack (leading n_super axis)."""
-    n_super = num_superblocks(params)
-    if n_super == 0:
-        return {}
-
-    def one_layer_cache(i):
-        kind = cfg.layer_kind(i)
-        if kind == "attn":
-            return attn.init_kv_cache(cfg, batch_size, max_len, dtype,
-                                      window=cfg.layer_window(i))
-        if kind == "mamba":
-            return ssm_mod.mamba_init_state(cfg, batch_size)
-        if kind == "rwkv":
-            return ssm_mod.rwkv_init_state(cfg, batch_size)
-        raise ValueError(kind)
-
-    one = {f"layer{i}": one_layer_cache(i) for i in range(cfg.pattern_period)}
-    return jax.tree.map(lambda x: jnp.broadcast_to(x, (n_super,) + x.shape).copy(), one)
+    return _layer_states(params, cfg, batch_size, lambda w: attn.init_kv_cache(
+        cfg, batch_size, max_len, dtype, window=w))
 
 
 def lm_init_paged_cache(params, cfg: ModelConfig, batch_size: int,
@@ -244,29 +343,15 @@ def lm_init_paged_cache(params, cfg: ModelConfig, batch_size: int,
     adds per-slot float32 scale leaves — see ``attn.init_paged_kv_cache``);
     window rings and recurrent state stay in ``dtype``, since they are
     per-row O(window)/O(1) state, not the HBM-dominant paged working set."""
-    n_super = num_superblocks(params)
-    if n_super == 0:
-        return {}
     pool_dtype = dtype if kv_dtype is None else kv_dtype
 
-    def one_layer_cache(i):
-        kind = cfg.layer_kind(i)
-        if kind == "attn":
-            w = cfg.layer_window(i)
-            if w > 0:
-                return attn.init_kv_cache(cfg, batch_size, max_len, dtype,
-                                          window=w)
-            return attn.init_paged_kv_cache(cfg, num_blocks, block_size,
-                                            pool_dtype)
-        if kind == "mamba":
-            return ssm_mod.mamba_init_state(cfg, batch_size)
-        if kind == "rwkv":
-            return ssm_mod.rwkv_init_state(cfg, batch_size)
-        raise ValueError(kind)
-
-    one = {f"layer{i}": one_layer_cache(i) for i in range(cfg.pattern_period)}
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (n_super,) + x.shape).copy(), one)
+    def attn_state(w):
+        if w > 0:
+            return attn.init_kv_cache(cfg, batch_size, max_len, dtype,
+                                      window=w)
+        return attn.init_paged_kv_cache(cfg, num_blocks, block_size,
+                                        pool_dtype)
+    return _layer_states(params, cfg, batch_size, attn_state)
 
 
 def lm_init_prefill_carry(params, cfg: ModelConfig, max_len: int,
@@ -275,66 +360,22 @@ def lm_init_prefill_carry(params, cfg: ModelConfig, max_len: int,
     threads between chunks — window rings and recurrent states.  Paged
     layers carry nothing ({}): their K/V goes straight into the shared pool
     through the block table, so admission never copies it."""
-    n_super = num_superblocks(params)
-    if n_super == 0:
-        return {}
-
-    def one_layer_carry(i):
-        kind = cfg.layer_kind(i)
-        if kind == "attn":
-            w = cfg.layer_window(i)
-            if w > 0:
-                return attn.init_kv_cache(cfg, 1, max_len, dtype, window=w)
-            return {}
-        if kind == "mamba":
-            return ssm_mod.mamba_init_state(cfg, 1)
-        if kind == "rwkv":
-            return ssm_mod.rwkv_init_state(cfg, 1)
-        raise ValueError(kind)
-
-    one = {f"layer{i}": one_layer_carry(i) for i in range(cfg.pattern_period)}
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x, (n_super,) + x.shape).copy(), one)
+    return _layer_states(params, cfg, 1, lambda w: attn.init_kv_cache(
+        cfg, 1, max_len, dtype, window=w) if w > 0 else {})
 
 
-def _prefill_layer(lp, cache_l, cfg: ModelConfig, i: int, x, positions):
-    """One layer over the full prompt, filling its decode cache.
+# ---------------------------------------------------------------------------
+# Prefill (whole prompt, or one chunk of it)
+# ---------------------------------------------------------------------------
 
-    The residual math is identical to ``_apply_layer`` (train path, aux
-    losses dropped — inference); the cache comes out as if the prompt had
-    been decoded token by token."""
-    kind = cfg.layer_kind(i)
-    if kind == "attn":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = attn.attn_prefill(lp["attn"], cfg, h, cache_l, positions,
-                                       window=cfg.layer_window(i))
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        if cfg.layer_is_moe(i):
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-        else:
-            y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
-        x = x + y
-    elif kind == "mamba":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = ssm_mod.mamba_prefill(lp["mamba"], cfg, h, cache_l)
-        x = x + y
-        if cfg.layer_is_moe(i):
-            h = apply_norm(lp["ln2"], x, cfg.norm)
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-            x = x + y
-    elif kind == "rwkv":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = ssm_mod.rwkv_time_mix_prefill(lp["rwkv_tm"], cfg, h, cache_l)
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        y, cache_l = ssm_mod.rwkv_channel_mix_prefill(lp["rwkv_tm"], cfg, h,
-                                                      cache_l)
-        x = x + y
-    else:
-        raise ValueError(kind)
-    x = maybe_shard(x, P(("pod", "data"), "model", None))
-    return x, cache_l
+
+# The recurrent mixers of a prefill (whole prompt or one chunk of it) and
+# of a decode step (one token, or verify's per-token replay).
+_PREFILL = dict(mamba=ssm_mod.mamba_prefill,
+                rwkv_time=ssm_mod.rwkv_time_mix_prefill,
+                rwkv_channel=ssm_mod.rwkv_channel_mix_prefill)
+_DECODE = dict(mamba=ssm_mod.mamba_decode, rwkv_time=ssm_mod.rwkv_decode,
+               rwkv_channel=ssm_mod.rwkv_channel_mix_decode)
 
 
 def lm_prefill(params, cfg: ModelConfig, tokens, cache, embeds=None,
@@ -345,76 +386,12 @@ def lm_prefill(params, cfg: ModelConfig, tokens, cache, embeds=None,
     per-row cursor ``index = full((B,), S_total)``); a continuous-batching
     engine prefills one request at a time (B=1, the prompt's exact length)
     and scatters the row into a freed slot of the live batch cache."""
-    x = embed_tokens(params, cfg, tokens, embeds)
-    B, S = x.shape[0], x.shape[1]
-    if positions is None:
-        positions = _positions_for(cfg, B, S)
-    x = maybe_shard(x, P(("pod", "data"), None, None))
-    n_super = num_superblocks(params)
-    if n_super > 0:
-        def scan_fn(x, sb_and_cache):
-            sb, cache_sb = sb_and_cache
-            for i in range(cfg.pattern_period):
-                x, new_c = _prefill_layer(sb[f"layer{i}"], cache_sb[f"layer{i}"],
-                                          cfg, i, x, positions)
-                cache_sb[f"layer{i}"] = new_c
-            return x, cache_sb
-        x, cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap(x @ head, cfg.final_logit_softcap)
-    logits = maybe_shard(logits, P(("pod", "data"), None, "model"))
-    return logits, cache
-
-
-def _prefill_chunk_layer(lp, cache_l, carry_l, cfg: ModelConfig, i: int, x,
-                         ctx_len, positions, block_table):
-    """One layer over ONE prefill chunk (B, C, D) at offset ``ctx_len``.
-
-    Paged attention layers read/write the shared pool (from ``cache_l``)
-    through the block table; window/mamba/rwkv layers thread the B=1 carry
-    (``carry_l``) exactly as the full prefill threads its cache — binary-
-    decomposed chunks are exact (never padded), so recurrent states see
-    only real tokens and chunked == one-shot prefill numerically."""
-    kind = cfg.layer_kind(i)
-    if kind == "attn":
-        w = cfg.layer_window(i)
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        if w > 0:
-            y, carry_l = attn.attn_prefill_chunk(lp["attn"], cfg, h, carry_l,
-                                                 ctx_len, positions, w)
-        else:
-            y, cache_l = attn.attn_prefill_chunk(lp["attn"], cfg, h, cache_l,
-                                                 ctx_len, positions, 0,
-                                                 block_table=block_table)
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        if cfg.layer_is_moe(i):
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-        else:
-            y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
-        x = x + y
-    elif kind == "mamba":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, carry_l = ssm_mod.mamba_prefill(lp["mamba"], cfg, h, carry_l)
-        x = x + y
-        if cfg.layer_is_moe(i):
-            h = apply_norm(lp["ln2"], x, cfg.norm)
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-            x = x + y
-    elif kind == "rwkv":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, carry_l = ssm_mod.rwkv_time_mix_prefill(lp["rwkv_tm"], cfg, h,
-                                                   carry_l)
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        y, carry_l = ssm_mod.rwkv_channel_mix_prefill(lp["rwkv_tm"], cfg, h,
-                                                      carry_l)
-        x = x + y
-    else:
-        raise ValueError(kind)
-    x = maybe_shard(x, P(("pod", "data"), "model", None))
-    return x, cache_l, carry_l
+    x, positions = _embed_prompt(params, cfg, tokens, embeds, positions)
+    mix = Mixers(
+        attn=lambda p, cfg, h, s, window: attn.attn_prefill(
+            p, cfg, h, s, positions, window), **_PREFILL)
+    x, cache, _ = _stack(params, cfg, x, mix, cache)
+    return maybe_shard(_head(params, cfg, x), _LOGITS), cache
 
 
 def lm_prefill_chunk(params, cfg: ModelConfig, tokens, cache, carry,
@@ -423,100 +400,33 @@ def lm_prefill_chunk(params, cfg: ModelConfig, tokens, cache, carry,
     ``ctx_len .. ctx_len + C - 1`` (``ctx_len`` traced — one executable per
     chunk WIDTH, not per offset).  Paged K/V lands in the shared pool of
     ``cache`` through ``block_table`` (B, NB); window rings and recurrent
-    states thread through the B=1 ``carry``.  Returns (last-position logits
-    (B, 1, V), cache, carry): only the final chunk's logits are consumed
-    (first-token sampling), so the lm_head matmul stays O(1) per chunk."""
+    states thread through the B=1 ``carry`` exactly as the full prefill
+    threads its cache — binary-decomposed chunks are exact (never padded),
+    so recurrent states see only real tokens and chunked == one-shot
+    prefill numerically.  Returns (last-position logits (B, 1, V), cache,
+    carry): only the final chunk's logits are consumed (first-token
+    sampling), so the lm_head matmul stays O(1) per chunk."""
     B, C = tokens.shape
     ctx_len = jnp.asarray(ctx_len, jnp.int32)
-    x = embed_tokens(params, cfg, tokens, offset=ctx_len)
-    pos = ctx_len + jnp.arange(C)[None, :]
-    positions = (jnp.broadcast_to(pos[None], (3, B, C))
-                 if cfg.position == "mrope"
-                 else jnp.broadcast_to(pos, (B, C)))
-    x = maybe_shard(x, P(("pod", "data"), None, None))
-    n_super = num_superblocks(params)
-    if n_super > 0:
-        def scan_fn(x, sbc):
-            sb, cache_sb, carry_sb = sbc
-            for i in range(cfg.pattern_period):
-                x, new_cache, new_carry = _prefill_chunk_layer(
-                    sb[f"layer{i}"], cache_sb[f"layer{i}"],
-                    carry_sb[f"layer{i}"], cfg, i, x, ctx_len, positions,
-                    block_table)
-                cache_sb[f"layer{i}"] = new_cache
-                carry_sb[f"layer{i}"] = new_carry
-            return x, (cache_sb, carry_sb)
-        x, (cache, carry) = jax.lax.scan(
-            scan_fn, x, (params["blocks"], cache, carry))
-    x = apply_norm(params["final_norm"], x[:, -1:], cfg.norm)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap(x @ head, cfg.final_logit_softcap)
-    return logits, cache, carry
+    x = maybe_shard(embed_tokens(params, cfg, tokens, offset=ctx_len), _ROWS)
+    positions = _positions(cfg, ctx_len + jnp.arange(C)[None, :], B)
+    mix = Mixers(
+        attn=lambda p, cfg, h, s, window: attn.attn_prefill_chunk(
+            p, cfg, h, s, ctx_len, positions, window,
+            block_table=block_table), **_PREFILL)
+    # Each layer threads its carry, or the shared pool where it carries
+    # nothing (paged layers); the rest of `cache` is the live batch's.
+    pooled = [n for n, c in carry.items() if not c]
+    state = {n: cache[n] if n in pooled else c for n, c in carry.items()}
+    x, state, _ = _stack(params, cfg, x, mix, state)
+    cache = {**cache, **{n: state[n] for n in pooled}}
+    carry = {**state, **{n: carry[n] for n in pooled}}
+    return _head(params, cfg, x[:, -1:]), cache, carry
 
 
-def _verify_layer(lp, cache_l, cfg: ModelConfig, i: int, x, index, positions,
-                  block_table, write_mask):
-    """One layer over a (B, C) verify chunk at per-row offsets ``index``.
-
-    Attention layers score the chunk through block tables (paged) or
-    deferred ring commits (window).  Recurrent mamba/rwkv layers replay the
-    chunk as C single-token decode steps — the EXACT per-token decode math,
-    so the replay is bitwise identical to sequential decode — and
-    checkpoint the state after every step into ``pending["states"]``: a
-    (C+1)-deep checkpoint ring (entry 0 = the pre-round state) from which
-    :func:`lm_spec_commit` rewinds each row to its accepted length with one
-    index-select, O(γ·state) memory per layer."""
-    kind = cfg.layer_kind(i)
-    if kind == "attn":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = attn.attn_verify_chunk(lp["attn"], cfg, h, cache_l,
-                                            index, positions,
-                                            cfg.layer_window(i),
-                                            block_table=block_table,
-                                            write_mask=write_mask)
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        if cfg.layer_is_moe(i):
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-        else:
-            y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
-        x = x + y
-    elif kind == "mamba":
-        def step(state, xt):
-            xt = xt[:, None, :]
-            h = apply_norm(lp["ln1"], xt, cfg.norm)
-            y, state = ssm_mod.mamba_decode(lp["mamba"], cfg, h, state)
-            xo = xt + y
-            if cfg.layer_is_moe(i):
-                h = apply_norm(lp["ln2"], xo, cfg.norm)
-                y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-                xo = xo + y
-            return state, (xo[:, 0], state)
-        _, (xs, states) = jax.lax.scan(step, cache_l, jnp.moveaxis(x, 1, 0))
-        x = jnp.moveaxis(xs, 0, 1)
-        cache_l = {"pending": {"states": jax.tree.map(
-            lambda s0, ss: jnp.concatenate([s0[None].astype(ss.dtype), ss], 0),
-            cache_l, states)}}
-    elif kind == "rwkv":
-        def step(state, xt):
-            xt = xt[:, None, :]
-            h = apply_norm(lp["ln1"], xt, cfg.norm)
-            y, state = ssm_mod.rwkv_decode(lp["rwkv_tm"], cfg, h, state)
-            xo = xt + y
-            h = apply_norm(lp["ln2"], xo, cfg.norm)
-            y, state = ssm_mod.rwkv_channel_mix_decode(lp["rwkv_tm"], cfg, h,
-                                                       state)
-            xo = xo + y
-            return state, (xo[:, 0], state)
-        _, (xs, states) = jax.lax.scan(step, cache_l, jnp.moveaxis(x, 1, 0))
-        x = jnp.moveaxis(xs, 0, 1)
-        cache_l = {"pending": {"states": jax.tree.map(
-            lambda s0, ss: jnp.concatenate([s0[None].astype(ss.dtype), ss], 0),
-            cache_l, states)}}
-    else:
-        raise ValueError(kind)
-    x = maybe_shard(x, P(("pod", "data"), "model", None))
-    return x, cache_l
+# ---------------------------------------------------------------------------
+# Speculative verify
+# ---------------------------------------------------------------------------
 
 
 def lm_verify(params, cfg: ModelConfig, tokens, cache, index, block_table,
@@ -528,34 +438,20 @@ def lm_verify(params, cfg: ModelConfig, tokens, cache, index, block_table,
     offsets (``write_mask`` (B, C) redirects inactive rows / positions at
     or past the row's limit to the trash page); window rings defer their
     advance into ``pending`` entries that :func:`lm_spec_commit` applies
-    once the accept rule picks each row's accepted prefix.  Returns
-    (logits (B, C, V), cache)."""
+    once the accept rule picks each row's accepted prefix.  Recurrent
+    layers replay the chunk through the decode mixers (:func:`_replay`).
+    Returns (logits (B, C, V), cache)."""
     B, C = tokens.shape
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
     pos = index[:, None] + jnp.arange(C)[None, :]              # (B, C)
-    x = params["embed"][tokens]
-    if cfg.position == "absolute":
-        x = x + params["pos_embed"][pos]
-    positions = (jnp.broadcast_to(pos[None], (3, B, C))
-                 if cfg.position == "mrope" else pos)
-    x = maybe_shard(x, P(("pod", "data"), None, None))
-    n_super = num_superblocks(params)
-    if n_super > 0:
-        def scan_fn(x, sb_and_cache):
-            sb, cache_sb = sb_and_cache
-            for i in range(cfg.pattern_period):
-                x, new_c = _verify_layer(sb[f"layer{i}"],
-                                         cache_sb[f"layer{i}"], cfg, i, x,
-                                         index, positions, block_table,
-                                         write_mask)
-                cache_sb[f"layer{i}"] = new_c
-            return x, cache_sb
-        x, cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap(x @ head, cfg.final_logit_softcap)
-    logits = maybe_shard(logits, P(("pod", "data"), None, "model"))
-    return logits, cache
+    x = maybe_shard(_embed_at(params, cfg, tokens, pos), _ROWS)
+    positions = _positions(cfg, pos, B)
+    mix = Mixers(
+        attn=lambda p, cfg, h, s, window: attn.attn_verify_chunk(
+            p, cfg, h, s, index, positions, window, block_table=block_table,
+            write_mask=write_mask), per_token=True, **_DECODE)
+    x, cache, _ = _stack(params, cfg, x, mix, cache)
+    return maybe_shard(_head(params, cfg, x), _LOGITS), cache
 
 
 def lm_spec_commit(cache, index, acc):
@@ -586,44 +482,9 @@ def lm_spec_commit(cache, index, acc):
     return out
 
 
-def _decode_layer(lp, cache_l, cfg: ModelConfig, i: int, x, index, positions,
-                  block_table=None, write_mask=None):
-    kind = cfg.layer_kind(i)
-    if kind == "attn":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        if "k_pages" in cache_l or "latent_pages" in cache_l:
-            y, cache_l = attn.attn_decode_paged(lp["attn"], cfg, h, cache_l,
-                                                block_table, index, positions,
-                                                write_mask=write_mask)
-        else:
-            y, cache_l = attn.attn_decode(lp["attn"], cfg, h, cache_l, index,
-                                          positions,
-                                          window=cfg.layer_window(i))
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        if cfg.layer_is_moe(i):
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-        else:
-            y = mlp_mod.mlp_apply(lp["mlp"], cfg, h)
-        x = x + y
-    elif kind == "mamba":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = ssm_mod.mamba_decode(lp["mamba"], cfg, h, cache_l)
-        x = x + y
-        if cfg.layer_is_moe(i):
-            h = apply_norm(lp["ln2"], x, cfg.norm)
-            y, _ = mlp_mod.moe_apply(lp["moe"], cfg, h)
-            x = x + y
-    elif kind == "rwkv":
-        h = apply_norm(lp["ln1"], x, cfg.norm)
-        y, cache_l = ssm_mod.rwkv_decode(lp["rwkv_tm"], cfg, h, cache_l)
-        x = x + y
-        h = apply_norm(lp["ln2"], x, cfg.norm)
-        y, cache_l = ssm_mod.rwkv_channel_mix_decode(lp["rwkv_tm"], cfg, h, cache_l)
-        x = x + y
-    return x, cache_l
-
-
+# ---------------------------------------------------------------------------
+# Decode (single token)
+# ---------------------------------------------------------------------------
 def _commit_paged_writes(cache):
     """Apply the decode step's deferred pool writes, batched across the
     whole layer scan: each paged layer's attention deferred its one-token
@@ -679,27 +540,15 @@ def lm_decode_step(params, cfg: ModelConfig, tokens, cache, index,
     page (the contiguous freeze-select equivalent)."""
     B = tokens.shape[0]
     index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (B,))
-    x = embed_tokens(params, cfg, tokens, offset=0)
-    if cfg.position == "absolute":
-        x = params["embed"][tokens] + params["pos_embed"][index][:, None, :]
+    x = _embed_at(params, cfg, tokens, index[:, None])
     if positions is None:
-        pos = index[:, None]
-        positions = jnp.broadcast_to(pos[None], (3, B, 1)) \
-            if cfg.position == "mrope" else pos
-    n_super = num_superblocks(params)
-    if n_super > 0:
-        def scan_fn(x, sb_and_cache):
-            sb, cache_sb = sb_and_cache
-            for i in range(cfg.pattern_period):
-                x, new_c = _decode_layer(sb[f"layer{i}"], cache_sb[f"layer{i}"],
-                                         cfg, i, x, index, positions,
-                                         block_table=block_table,
-                                         write_mask=write_mask)
-                cache_sb[f"layer{i}"] = new_c
-            return x, cache_sb
-        x, cache = jax.lax.scan(scan_fn, x, (params["blocks"], cache))
-        cache = _commit_paged_writes(cache)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = softcap(x @ head, cfg.final_logit_softcap)
-    return logits, cache
+        positions = _positions(cfg, index[:, None], B)
+
+    def attn_mix(p, cfg, h, s, window):
+        if "k_pages" in s or "latent_pages" in s:
+            return attn.attn_decode_paged(p, cfg, h, s, block_table, index,
+                                          positions, write_mask=write_mask)
+        return attn.attn_decode(p, cfg, h, s, index, positions, window=window)
+    x, cache, _ = _stack(params, cfg, x, Mixers(attn=attn_mix, **_DECODE),
+                         cache, shard=False)
+    return _head(params, cfg, x), _commit_paged_writes(cache)

@@ -19,7 +19,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import checkpointer as ckpt
-from repro.configs.base import ModelConfig, SSMConfig
+from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
 from repro.core import expansion as exp
 from repro.distributed import sharding as shd
 from repro.launch import mesh as mesh_lib
@@ -43,8 +43,20 @@ CFG_RWKV = ModelConfig(name="srv-rwkv", family="ssm", num_layers=4,
                        position="none", norm="layernorm",
                        block_pattern=("rwkv",),
                        ssm=SSMConfig(kind="rwkv6", head_dim=16))
+CFG_MLA = dataclasses.replace(CFG_DENSE, name="srv-mla", attention="mla",
+                              mla_kv_lora_rank=8)
+# capacity_factor = num_experts / top_k: no token is dropped, so rows stay
+# independent and a row's logits do not depend on its batch.
+CFG_MOE = dataclasses.replace(CFG_DENSE, name="srv-moe", family="moe",
+                              moe=MoEConfig(num_experts=4, top_k=2,
+                                            capacity_factor=2.0))
+CFG_HYBRID = dataclasses.replace(CFG_DENSE, name="srv-hybrid",
+                                 family="hybrid",
+                                 block_pattern=("attn", "mamba"),
+                                 ssm=SSMConfig(d_state=4))
 ARCH_CFGS = {"dense": CFG_DENSE, "window": CFG_WINDOW, "mamba": CFG_MAMBA,
-             "rwkv": CFG_RWKV}
+             "rwkv": CFG_RWKV, "mla": CFG_MLA, "moe": CFG_MOE,
+             "hybrid": CFG_HYBRID}
 
 
 def _params(cfg, seed=0):
@@ -154,6 +166,41 @@ def test_prefill_matches_token_by_token_decode(arch):
     # and the prefill forward is the train-path forward
     full = api.apply(params, cfg, {"tokens": toks})
     np.testing.assert_allclose(np.asarray(logits_pf), np.asarray(full),
+                               rtol=0, atol=1e-4)
+
+
+@pytest.mark.parametrize("arch", list(ARCH_CFGS))
+def test_chunked_prefill_and_verify_match_apply(arch):
+    """The two other multi-token entry points score what the train-path
+    forward scores over the whole sequence: a prompt prefilled in two
+    chunks through a paged cache (last-position logits of each chunk), then
+    a 3-token verify at the prompt's end (logits at all 3 positions)."""
+    cfg = ARCH_CFGS[arch]
+    api = registry.get_model(cfg)
+    params = _params(cfg)
+    C, G, BS, ML = 4, 3, 4, 16
+    toks = jnp.asarray(_prompts(cfg, B=1, P=2 * C + G))
+    full = np.asarray(api.apply(params, cfg, {"tokens": toks}))
+    table = jnp.arange(ML // BS, dtype=jnp.int32)[None]
+    cache = api.init_paged_cache(params, cfg, 1, ML // BS, BS, ML,
+                                 dtype=jnp.float32)
+    carry = api.init_prefill_carry(params, cfg, ML, dtype=jnp.float32)
+    chunk = jax.jit(functools.partial(api.prefill_chunk, cfg=cfg))
+    for c in range(2):
+        logits, cache, carry = chunk(params, tokens=toks[:, c * C:(c + 1) * C],
+                                     cache=cache, carry=carry,
+                                     block_table=table, ctx_len=c * C)
+        np.testing.assert_allclose(np.asarray(logits[:, 0]),
+                                   full[:, (c + 1) * C - 1],
+                                   rtol=0, atol=1e-4)
+    # Admit the row: carried layers (window rings, recurrent states) take
+    # their carry; paged layers (which carry {}) keep the pool.
+    cache = {n: c if c else cache[n] for n, c in carry.items()}
+    logits, _ = jax.jit(functools.partial(api.verify, cfg=cfg))(
+        params, tokens=toks[:, 2 * C:], cache=cache,
+        index=jnp.full((1,), 2 * C, jnp.int32), block_table=table,
+        write_mask=jnp.ones((1, G), bool))
+    np.testing.assert_allclose(np.asarray(logits), full[:, 2 * C:],
                                rtol=0, atol=1e-4)
 
 
