@@ -54,7 +54,7 @@ REMAT_DEFAULTS = {
 
 def default_remat(name: str) -> str:
     """Measured checkpoint policy for ``name`` when remat is enabled
-    (see REMAT_DEFAULTS); unmeasured configs recompute everything."""
+    (see REMAT_DEFAULTS); unmeasured configs take 'nothing'."""
     return REMAT_DEFAULTS.get(name, "nothing")
 
 

@@ -254,8 +254,9 @@ class TrainConfig:
     seed: int = 0
     dtype: str = "float32"           # compute dtype ('bfloat16' on TPU)
     # Activation checkpointing over the layer scan: False (off), True /
-    # 'nothing' (recompute everything), or 'dots' (save matmul outputs —
-    # per-arch measured defaults live in configs.REMAT_DEFAULTS).
+    # 'nothing' (recompute all but the flash kernel's residuals), or 'dots'
+    # (also save matmul outputs — per-arch measured defaults live in
+    # configs.REMAT_DEFAULTS); see models.transformer.remat_policy.
     remat: "bool | str" = False
     log_every: int = 10
 

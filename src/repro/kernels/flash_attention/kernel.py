@@ -54,7 +54,11 @@ walks q blocks for each k block — so nothing of size S x S is ever stored.
 ``dq`` also computes ``di`` from do and o at its first k block, and hands
 it to ``dk/dv`` as a statistic.  For GQA, dk/dv come out per q head and
 are summed over each group outside the kernel.  ``flash_attention_tpu`` is
-a ``jax.custom_vjp`` over the two.
+a ``jax.custom_vjp`` over the two.  Its residuals o and lse are tagged
+with the checkpoint names in ``RESIDUAL_NAMES``, so that a checkpoint
+policy can keep them (``models.transformer.remat_policy``): the backward of
+a checkpointed layer then recomputes q, k and v from the layer's input but
+never re-runs the forward kernel.
 """
 from __future__ import annotations
 
@@ -62,12 +66,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
 LANE_DENSE, HEAD_MAJOR = "lane_dense", "head_major"
+RESIDUAL_NAMES = ("flash_attention_o", "flash_attention_lse")
 _NT = (((1,), (1,)), ((), ()))           # contract the last dims: a @ b.T
 
 
@@ -454,6 +460,8 @@ def _attention_fwd(q, k, v, layout, g, hd, causal, window, softcap, block_q,
     o, lse = _fwd(q, k, v, layout=layout, g=g, hd=hd, causal=causal,
                   window=window, softcap=softcap, block_q=block_q,
                   block_k=block_k, interpret=interpret)
+    o = checkpoint_name(o, RESIDUAL_NAMES[0])
+    lse = checkpoint_name(lse, RESIDUAL_NAMES[1])
     return o, (q, k, v, o, lse)
 
 

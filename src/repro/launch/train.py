@@ -67,8 +67,8 @@ def main(argv=None):
                     choices=["off", "auto", "nothing", "dots"],
                     help="activation checkpointing: bare --remat picks the "
                     "arch's measured policy (configs.REMAT_DEFAULTS); "
-                    "'nothing' recomputes everything, 'dots' saves matmul "
-                    "outputs")
+                    "'nothing' recomputes every layer but the flash "
+                    "kernel's output, 'dots' also saves matmul outputs")
     ap.add_argument("--mesh", default="single",
                     help="mesh spec: single | host | prod | prod-multipod "
                     "| AxB (data x model), e.g. 4x2")

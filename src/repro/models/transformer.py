@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
+from repro.kernels.flash_attention.kernel import RESIDUAL_NAMES
 from repro.models import attention as attn
 from repro.models import mlp as mlp_mod
 from repro.models import ssm as ssm_mod
@@ -177,9 +178,23 @@ _LAYER_OUT = P(("pod", "data"), "model", None)
 _LOGITS = P(("pod", "data"), None, "model")
 
 # Activation checkpointing of the layer scan: off, or on with a policy —
-# True / "nothing" saves nothing (full recompute), "dots" saves matmul
+# True / "nothing" recomputes every layer's forward except the flash
+# attention kernel's output and log-sum-exp, "dots" also saves matmul
 # outputs (less recompute, more live memory).
 Remat = Union[bool, Literal["nothing", "dots"]]
+
+
+def remat_policy(remat: Remat):
+    """The checkpoint policy of the layer scan for a ``remat`` that is on.
+    Both keep the flash kernel's residuals (``RESIDUAL_NAMES``), so the
+    backward never re-runs the forward kernel; layers without the kernel
+    have no such names."""
+    policies = jax.checkpoint_policies
+    residuals = policies.save_only_these_names(*RESIDUAL_NAMES)
+    if remat == "dots":
+        return policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, residuals)
+    return residuals
 
 
 def _stack(params, cfg: ModelConfig, x, mix: Mixers, state=None,
@@ -207,10 +222,7 @@ def _stack(params, cfg: ModelConfig, x, mix: Mixers, state=None,
             sb_aux = sb_aux + a
         return (x, aux + sb_aux), new_state
     if remat:
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if remat == "dots"
-                  else jax.checkpoint_policies.nothing_saveable)
-        scan_fn = jax.checkpoint(scan_fn, policy=policy)
+        scan_fn = jax.checkpoint(scan_fn, policy=remat_policy(remat))
     (x, aux), state = jax.lax.scan(scan_fn, (x, aux),
                                    (params["blocks"], state))
     return x, state, aux

@@ -85,7 +85,7 @@ def test_flash_forward_backward_compiles(one_chip):
         return jnp.sum(flash_attention_tpu(q, k, v) ** 2)
 
     hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(one_chip))
-    # forward (recomputed residuals) + dq + dk/dv kernels
+    # forward + dq + dk/dv kernels
     assert hlo.count("tpu_custom_call") >= 3
 
 
@@ -140,6 +140,31 @@ def test_flash_gpt2_step_runs_on_the_model_layout(one_chip):
     assert not any(c in (f"f32[{B},{S},{H},{hd}]", f"f32[{B},{H},{S},{hd}]",
                          act) for c in _copies(hlo)), _copies(hlo)
     assert not re.search(rf"f32\[[\d,]*,{S},1\]", hlo)
+
+
+def test_gpt2_grad_under_remat_runs_the_forward_kernel_once(one_chip,
+                                                            monkeypatch):
+    """The gradient of ``gpt2-12l``'s loss at 2 layers under ``remat=
+    "nothing"``: the checkpoint keeps the forward kernel's o and lse, so
+    the backward recomputes the layer without re-running the kernel."""
+    from repro.models import transformer
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = GPT2.with_depth(2)
+    params = jax.eval_shape(lambda k: transformer.lm_init(k, cfg),
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _struct(a.shape, a.dtype, one_chip),
+                          params)
+    tokens = _struct((B, S), jnp.int32, one_chip)
+
+    def grad(p, tokens, labels):
+        return jax.grad(lambda p: transformer.lm_loss(
+            p, cfg, tokens, labels, remat="nothing")[0])(p)
+
+    hlo = _compile(grad, params, tokens, tokens)
+    assert set(_kernel_calls(hlo)) == {"flash_attention_fwd",
+                                       "flash_attention_dq",
+                                       "flash_attention_dkv"}
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
 
 
 def test_flash_gqa_hd128_compiles_lane_dense(one_chip):

@@ -19,7 +19,7 @@ from repro.kernels.paged_attention.kernel import paged_attention_tpu
 from repro.kernels.rwkv6.kernel import wkv_tpu
 from repro.kernels.rwkv6.ref import wkv_ref
 from repro.launch import mesh as mesh_lib
-from repro.models import common
+from repro.models import common, transformer
 
 
 # ---------------------------------------------------------------------------
@@ -122,6 +122,60 @@ def test_flash_attention_grad_vs_ref(Sq, Sk, H, KV, causal, window, softcap,
                          jax.grad(ref, (0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+def _pallas_calls(jaxpr, name):
+    """Number of ``pallas_call`` equations called ``name`` in ``jaxpr`` and
+    every jaxpr nested in its equations."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == name
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jax.extend.core.Jaxpr):
+                    n += _pallas_calls(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("H,KV,hd", [(4, 4, 64), (4, 2, 64)],
+                         ids=["lane_dense", "head_major"])
+@pytest.mark.parametrize("remat", ["nothing", "dots"])
+def test_flash_attention_residuals_survive_the_layer_checkpoint(H, KV, hd,
+                                                                 remat):
+    """Under the layer scan's checkpoint policy the backward keeps the
+    forward kernel's o and lse: the gradient holds one forward kernel (the
+    primal's), and equals the gradient without a checkpoint bit for bit."""
+    B, S, L = 1, 128, 2
+    D = H * hd
+    assert _plan((B, S, H, hd), (B, S, KV, hd)) == FLASH_PLANS[(H, KV, hd)]
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    ws = tuple(0.05 * jax.random.normal(ks[i], (L, D, n)) for i, n in
+               enumerate((D, KV * hd, KV * hd, D)))
+    x = jax.random.normal(ks[4], (B, S, D))
+
+    def layer(x, w):
+        wq, wk, wv, wo = w
+        o = flash_attention_tpu((x @ wq).reshape(B, S, H, hd),
+                                (x @ wk).reshape(B, S, KV, hd),
+                                (x @ wv).reshape(B, S, KV, hd),
+                                interpret=True)
+        return x + o.reshape(B, S, D) @ wo, None
+
+    def loss(ws, x, checkpointed):
+        body = (jax.checkpoint(layer, policy=transformer.remat_policy(remat))
+                if checkpointed else layer)
+        return jnp.sum(jax.lax.scan(body, x, ws)[0] ** 2)
+
+    grad = jax.grad(loss)
+    jaxpr = jax.make_jaxpr(grad, static_argnums=2)(ws, x, True).jaxpr
+    assert _pallas_calls(jaxpr, "flash_attention_fwd") == 1
+    assert _pallas_calls(jaxpr, "flash_attention_dq") == 1
+    assert _pallas_calls(jaxpr, "flash_attention_dkv") == 1
+    for got, want in zip(grad(ws, x, True), grad(ws, x, False)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 @pytest.mark.parametrize("name,plan", [
